@@ -22,26 +22,29 @@ Rows (``name,us_per_call,derived``):
     it is guessing).
 
 The jax rows run the lowered schedules on a forced 8-device host mesh in a
-subprocess (absent without jax — optional in the gate):
+subprocess pinned to ``JAX_PLATFORMS=cpu`` (absent without jax — optional
+in the gate).  Every time in them is CPU-host wall clock: the eight
+"devices" are XLA's CPU backend, so none of these rows is a device
+(accelerator) metric.
 
 ``exec_measured_<strategy>``
-    Median wall-clock of the lowered schedule on the host mesh
+    Median CPU-host wall clock of the lowered schedule on the host mesh
     (``us_per_call``) with the calibrated model's predicted cost in
     seconds as ``derived`` — the measured-vs-predicted table, one row per
     strategy on the host-scale Lassen preset.  Bit-identity vs the
     reference executor is asserted inside before timing.
 
 ``exec_wallclock_agreement``
-    Pairwise ordering agreement between the measured wall-clock ranking
+    Pairwise ordering agreement between the CPU-host wall-clock ranking
     and the calibrated model's predicted ranking on the host mesh.
     Reported, not gated: the host CPU mesh is a different machine from
     the preset the model describes — the *simulator* rows above are the
     apples-to-apples agreement gate.
 
 ``exec_launch_overhead``
-    Median wall-clock of launching the empty ``standard`` schedule (all
-    launch, no payload); ``derived`` is that overhead as a fraction of the
-    measured ``standard`` schedule time.
+    Median CPU-host wall clock of launching the empty ``standard``
+    schedule (all launch, no payload); ``derived`` is that overhead as a
+    fraction of the measured ``standard`` schedule time.
 
 ``exec_standard_vs_naive``
     The greedy edge-colored ``standard`` schedule vs the naive
@@ -174,7 +177,9 @@ def bench_exec_schedules():
         return []
     from repro.exec import pairwise_agreement
 
-    env = dict(os.environ)
+    # the child forces a host mesh: pin it to the CPU so that it never
+    # contends for a chip this process may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)
     if out.returncode != 0:

@@ -7,6 +7,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from . import (bench_paper, bench_kernels, bench_roofline, bench_delta,
                    bench_stack_backends, bench_llm_workloads, bench_faults,
                    bench_exec)

@@ -14,7 +14,7 @@ lowered schedule needs it.
 """
 from .calibrate import (CalibrationResult, SweepRecord, calibrate,
                         record_sweeps)
-from .lower import build_executor, execute
+from .lower import build_executor, execute, executor_program
 from .measure import (Measurement, launch_overhead, measure_strategies,
                       ordering, pairwise_agreement, predicted_costs,
                       time_schedule)
@@ -29,7 +29,7 @@ __all__ = [
     "COLORINGS", "UNIT_BYTES", "ExecPhase", "ExecRound", "ExecSchedule",
     "build_schedule", "pairs_subset_of_plan", "synth_payload", "units_for",
     "reference_delivered", "run_reference", "delivered_digest",
-    "build_executor", "execute",
+    "build_executor", "execute", "executor_program",
     "Measurement", "time_schedule", "launch_overhead", "measure_strategies",
     "predicted_costs", "ordering", "pairwise_agreement",
     "SweepRecord", "CalibrationResult", "record_sweeps", "calibrate",

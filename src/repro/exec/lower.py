@@ -1,12 +1,12 @@
 """Lower an :class:`~repro.exec.plan.ExecSchedule` to a jitted JAX program.
 
-The program is one ``jax.jit`` around one
-:func:`repro.parallel._jax_compat.shard_map` over a 1-D ``("rank",)`` mesh
+The program is one ``jax.jit`` around one ``jax.shard_map`` over a 1-D
+``("rank",)`` mesh
 (:func:`repro.launch.mesh.make_rank_mesh`): every simulated MPI rank owns
 one mesh device, its row of the holding/delivered buffers, and its rows of
 each round's index tables.  Per round the body gathers the rank's ``pack``
 slots from its holding buffer, moves them with a single static
-:func:`~repro.parallel._jax_compat.ppermute` (the round's permutation is
+``jax.lax.ppermute`` (the round's permutation is
 baked in at trace time — rounds unroll, no dynamic control flow), and
 scatter-adds the received slots into the holding (``stage``) and delivered
 (``final``) buffers.  Padding flows through the sink column, which both
@@ -44,26 +44,15 @@ def initial_buffers(schedule: ExecSchedule) -> tuple[np.ndarray, np.ndarray]:
     return hold, deliv
 
 
-def build_executor(schedule: ExecSchedule, mesh=None):
-    """Compile ``schedule`` into a zero-argument callable returning the
-    delivered ``(n_procs, n_units)`` int32 matrix (host numpy, sink
-    trimmed).
-
-    ``mesh`` is the 1-D ``("rank",)`` mesh to run on, defaulting to
-    :func:`repro.launch.mesh.make_rank_mesh` over the schedule's rank
-    count.  The callable re-runs the jitted program on each invocation
-    (compilation is cached by jax), which is what
-    :func:`repro.exec.measure.time_schedule` times.
-    """
+def executor_program(schedule: ExecSchedule, mesh):
+    """The jitted ``shard_map`` program of ``schedule`` on ``mesh`` and its
+    host arguments: ``fn(*args)`` returns the delivered ``(n_procs,
+    n_units + 1)`` int32 matrix, sink column included, sharded by rank.
+    Lowering ``fn`` with shapes instead of ``args`` compiles it for a mesh
+    of described devices."""
     import jax
 
-    from repro.launch.mesh import make_rank_mesh
-    from repro.parallel._jax_compat import ppermute, shard_map
-
-    if mesh is None:
-        mesh = make_rank_mesh(schedule.n_procs)
     hold0, deliv0 = initial_buffers(schedule)
-
     perms = []
     tables = []
     for phase in schedule.phases:
@@ -78,7 +67,7 @@ def build_executor(schedule: ExecSchedule, mesh=None):
         h, dv = hold[0], deliv[0]
         for perm, (pack, stage, final) in zip(perms, round_tables):
             send = h[pack[0]]
-            recv = ppermute(send, "rank", perm)
+            recv = jax.lax.ppermute(send, "rank", perm)
             h = h.at[stage[0]].add(recv)
             dv = dv.at[final[0]].add(recv)
         return dv[None]
@@ -86,8 +75,29 @@ def build_executor(schedule: ExecSchedule, mesh=None):
     spec = jax.sharding.PartitionSpec("rank")
     args = (hold0, deliv0, tables)
     in_specs = jax.tree_util.tree_map(lambda _: spec, args)
-    fn = jax.jit(shard_map(step, mesh=mesh, in_specs=in_specs,
-                           out_specs=spec))
+    fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                               out_specs=spec))
+    return fn, args
+
+
+def build_executor(schedule: ExecSchedule, mesh=None):
+    """Compile ``schedule`` into a zero-argument callable returning the
+    delivered ``(n_procs, n_units)`` int32 matrix (host numpy, sink
+    trimmed).
+
+    ``mesh`` is the 1-D ``("rank",)`` mesh to run on, defaulting to
+    :func:`repro.launch.mesh.make_rank_mesh` over the schedule's rank
+    count.  The callable re-runs the jitted program of
+    :func:`executor_program` on each invocation (compilation is cached by
+    jax), which is what :func:`repro.exec.measure.time_schedule` times.
+    """
+    import jax
+
+    from repro.launch.mesh import make_rank_mesh
+
+    if mesh is None:
+        mesh = make_rank_mesh(schedule.n_procs)
+    fn, args = executor_program(schedule, mesh)
 
     def run() -> np.ndarray:
         out = jax.block_until_ready(fn(*args))

@@ -12,13 +12,13 @@ device implementations of all three:
     path: O(total messages) scatter work, the whole queue sweep one device
     program with no host round-trip between rounds.
 ``backend='pallas'``
-    Fused Pallas kernels.  :func:`fused_segment_reduce` tiles the message
-    stream into ``_CHUNK``-wide grid steps and scatter-accumulates each
-    chunk into the full padded output row kept resident across the grid
-    (the flash-attention accumulate idiom) — sums and maxima in one launch,
-    O(messages) work, so there is no one-hot work ceiling and no size
-    reroute.  The queue walk wraps the same lock-step Fenwick rounds in a
-    single Pallas program.  On hosts without a TPU/GPU the kernels run in
+    A fused Pallas segment reduce.  :func:`fused_segment_reduce` sorts the
+    keys on the host and reduces one-hot membership tiles of (segment tile
+    x message chunk) pairs — sums and maxima in one launch, with no vector
+    scatter (Mosaic has no lowering for one) and a bounded VMEM tile at any
+    arena size.  The queue walk is the jitted XLA walk of ``'jax'``: a
+    per-lane Fenwick walk needs dynamic gathers and scatters that Mosaic
+    cannot lower either.  On hosts without a TPU the kernel runs in
     interpret mode (parity, not speed).
 ``backend='auto'`` (the resolved form of ``backend=None``)
     The autotuned default: picks numpy below the measured numpy/jax
@@ -60,7 +60,7 @@ from repro.comm.health import get_health
 BACKENDS = ("numpy", "jax", "pallas", "auto")
 
 _CHUNK = 512        # messages per fused-kernel grid step
-_LANE = 128         # lane tile: device output rows pad to multiples of this
+_LANE = 128         # segments per fused-kernel output tile
 _SEG_BLOCK = _LANE  # historical alias (the retired one-hot kernel's block)
 
 
@@ -406,96 +406,170 @@ def _size_of(a) -> int:
 
 
 # -- fused Pallas segment reduce ---------------------------------------------
+#
+# Mosaic has no vector scatter, so the kernel never indexes by segment id.
+# The host sorts the message keys once per call; each grid step then takes
+# one (segment tile x message chunk) pair and reduces a one-hot membership
+# tile on the VPU.  The pairs come in tile order, so a tile's output block
+# stays resident in VMEM across its chunks.  A sorted chunk overlaps a
+# contiguous run of tiles, so there are at most n_chunks + n_tiles pairs:
+# the work stays O(messages * _LANE + segments * _CHUNK) and VMEM holds one
+# (_LANE, _CHUNK) tile whatever the arena size.
 
-def _fused_segreduce_kernel(ids_ref, vals_ref, sum_ref, max_ref):
-    """One grid step: scatter-accumulate chunk ``c`` into the resident row.
+def _segreduce_layout(seg_ids, n_seg: int):
+    """Host-side schedule for :func:`_segreduce_kernel`.
 
-    The output blocks map to ``(0, 0)`` on every step, so they stay resident
-    in VMEM across the whole grid while each step's ``(1, _CHUNK)`` message
-    tile streams through — sums and maxima in the same pass.  Padded lanes
-    carry the sink segment id (the last padded column) and neutral values,
-    so no masking is needed.
+    Returns ``(order, ids, tile_of, chunk_of, n_items)``: the stable sort
+    permutation of the messages, the sorted keys padded to whole chunks
+    with the sink key ``n_seg`` and shaped ``(n_chunks, 1, _CHUNK)``, and
+    the grid's (tile, chunk) pairs.  Every tile gets at least one pair, so
+    empty tiles are written too; pairs past ``n_items`` repeat the last
+    one and do no work.
     """
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
+    from repro.comm.primitives import segmented_arange
 
-    c = pl.program_id(0)
+    ids = np.asarray(seg_ids, dtype=np.int64)
+    n = ids.size
+    n_chunks = max(1, -(-n // _CHUNK))
+    n_tiles = -(-(n_seg + 1) // _LANE)            # +1: the sink key's tile
+    order = np.argsort(ids, kind="stable").astype(np.int32)
+    ids_s = np.full(n_chunks * _CHUNK, n_seg, dtype=np.int32)
+    ids_s[:n] = ids[order]
+    first_t = ids_s[::_CHUNK] // _LANE
+    last_t = ids_s[_CHUNK - 1::_CHUNK] // _LANE
+    tiles = np.arange(n_tiles)
+    lo = np.searchsorted(last_t, tiles, side="left")
+    hi = np.searchsorted(first_t, tiles, side="right")
+    cnt = np.maximum(hi - lo, 1)
+    lo = np.minimum(lo, n_chunks - 1)
+    tile_of = np.repeat(tiles, cnt)
+    chunk_of = lo[tile_of] + segmented_arange(cnt)
+    n_items = tile_of.size
+    width = n_chunks + n_tiles
+    tile_of = np.pad(tile_of, (0, width - n_items), mode="edge")
+    chunk_of = np.pad(chunk_of, (0, width - n_items), mode="edge")
+    return (order, ids_s.reshape(n_chunks, 1, _CHUNK),
+            tile_of.astype(np.int32), chunk_of.astype(np.int32),
+            np.asarray([n_items], dtype=np.int32))
 
-    @pl.when(c == 0)
-    def _init():
-        sum_ref[...] = jnp.zeros_like(sum_ref)
-        max_ref[...] = jnp.full_like(max_ref, -jnp.inf)
 
-    ids = ids_ref[0, :]
-    vals = vals_ref[0, :]
-    sum_ref[0, :] = sum_ref[0, :].at[ids].add(vals)
-    max_ref[0, :] = max_ref[0, :].at[ids].max(vals)
-
-
-@functools.cache
-def _pallas_segreduce(n_pad: int, s_pad: int):
+def _segreduce_kernel(tile_ref, chunk_ref, n_items_ref, ids_ref, vals_ref,
+                      sum_ref, max_ref, acc_sum, acc_max):
+    """One grid step: fold message chunk ``chunk_of[w]`` into segment tile
+    ``tile_of[w]``.  The accumulators are columns (segments on sublanes);
+    the output row is their transpose, rewritten every step so that it is
+    final whenever the tile's block is written back."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    return pl.pallas_call(
-        _fused_segreduce_kernel,
-        grid=(n_pad // _CHUNK,),
-        in_specs=[
-            pl.BlockSpec((1, _CHUNK), lambda c: (0, c)),
-            pl.BlockSpec((1, _CHUNK), lambda c: (0, c)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s_pad), lambda c: (0, 0)),
-            pl.BlockSpec((1, s_pad), lambda c: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, s_pad), jnp.float32),
-            jax.ShapeDtypeStruct((1, s_pad), jnp.float32),
-        ],
-        interpret=jax.default_backend() == "cpu",
-    )
+    w = pl.program_id(0)
+    tile = tile_ref[w]
+
+    @pl.when(jnp.logical_or(w == 0, tile_ref[jnp.maximum(w - 1, 0)] != tile))
+    def _init():
+        acc_sum[...] = jnp.zeros_like(acc_sum)
+        acc_max[...] = jnp.zeros_like(acc_max)
+
+    @pl.when(w < n_items_ref[0])
+    def _fold():
+        seg = tile * _LANE + jax.lax.broadcasted_iota(
+            jnp.int32, (_LANE, _CHUNK), 0)
+        v = jnp.where(ids_ref[...] == seg, vals_ref[...], 0.0)
+        acc_sum[...] += jnp.sum(v, axis=1, keepdims=True)
+        acc_max[...] = jnp.maximum(acc_max[...],
+                                   jnp.max(v, axis=1, keepdims=True))
+        sum_ref[...] = jnp.broadcast_to(acc_sum[...], (_LANE, _LANE)).T[:1]
+        max_ref[...] = jnp.broadcast_to(acc_max[...], (_LANE, _LANE)).T[:1]
+
+
+def _segreduce_spec(n_msgs: int, n_seg: int) -> dict:
+    """``pl.pallas_call`` arguments (grid spec and output shapes) of the
+    segment reduce for ``n_msgs`` messages into ``n_seg`` segments."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_chunks = max(1, -(-n_msgs // _CHUNK))
+    n_tiles = -(-(n_seg + 1) // _LANE)
+
+    def chunk_block():
+        return pl.BlockSpec((None, 1, _CHUNK),
+                            lambda w, tile, chunk, n: (chunk[w], 0, 0))
+
+    def tile_block():
+        return pl.BlockSpec((1, _LANE), lambda w, tile, chunk, n: (0, tile[w]))
+
+    row = jax.ShapeDtypeStruct((1, n_tiles * _LANE), jnp.float32)
+    return dict(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(n_chunks + n_tiles,),
+            in_specs=[chunk_block(), chunk_block()],
+            out_specs=[tile_block(), tile_block()],
+            scratch_shapes=[pltpu.VMEM((_LANE, 1), jnp.float32),
+                            pltpu.VMEM((_LANE, 1), jnp.float32)]),
+        out_shape=[row, row])
+
+
+def _segreduce_device(values, order, ids, tile_of, chunk_of, n_items, *,
+                      n_seg: int, call):
+    """The device side of the segment reduce (traced under ``jax.jit``):
+    gather the values into key order, pad them to whole chunks and run the
+    ``pallas_call`` ``call``.  Returns ``(sums, maxima)`` of length
+    ``n_seg``."""
+    import jax.numpy as jnp
+
+    vals = values.astype(jnp.float32)[order]
+    vals = jnp.pad(vals, (0, ids.size - vals.size)).reshape(ids.shape)
+    sums, maxs = call(tile_of, chunk_of, n_items, ids, vals)
+    return sums[0, :n_seg], maxs[0, :n_seg]
+
+
+@functools.cache
+def _pallas_segreduce(n_msgs: int, n_seg: int):
+    import jax
+    from jax.experimental import pallas as pl
+
+    call = pl.pallas_call(_segreduce_kernel, **_segreduce_spec(n_msgs, n_seg),
+                          interpret=jax.default_backend() == "cpu")
+    return jax.jit(functools.partial(_segreduce_device, n_seg=n_seg,
+                                     call=call))
 
 
 def fused_segment_reduce(values, seg_ids,
                          n_seg: int) -> tuple[np.ndarray, np.ndarray]:
     """One fused Pallas launch -> ``(segment sums, segment maxima)``.
 
-    Replaces the retired one-hot membership kernel: each grid step
-    scatter-accumulates one message chunk into the full padded output row
-    resident in VMEM, so the work is O(messages) — any arena size runs in
-    one launch and ``PALLAS_ONE_HOT_LIMIT`` rerouting is gone.  Padding to
-    ``s_pad = roundup(n_seg + 1, _LANE)`` guarantees a sink column for the
-    padded message lanes.  Empty segments report sum 0 and max 0 (the
-    contention reduction's inputs are non-negative byte counts).
+    The keys are sorted on the host and the kernel reduces one-hot
+    membership tiles of (segment tile x message chunk) pairs, so it needs
+    no vector scatter (which Mosaic cannot lower) and a bounded VMEM tile
+    at any arena size.  ``values`` may be a device array and stays on the
+    device; ``seg_ids`` is read on the host.  Empty segments report sum 0
+    and every maximum is floored at 0, exactly as the numpy reference
+    (the contention reduction's inputs are non-negative byte counts).
 
     Kernel failures degrade to the numpy reference pair via
     :func:`device_guard` (site ``kernel.segment_reduce``).
     """
-    values = np.asarray(values)
     seg_ids = np.asarray(seg_ids)
 
     def device_fn():
         import jax.numpy as jnp
 
-        n = values.size
-        n_pad = max(_CHUNK, -(-n // _CHUNK) * _CHUNK)
-        s_pad = max(_LANE, -(-(n_seg + 1) // _LANE) * _LANE)
-        ids = np.full((1, n_pad), s_pad - 1, dtype=np.int32)
-        ids[0, :n] = seg_ids
-        vals = np.zeros((1, n_pad), dtype=np.float32)
-        vals[0, :n] = values
-        s, mx = _pallas_segreduce(n_pad, s_pad)(jnp.asarray(ids),
-                                                jnp.asarray(vals))
-        sums = np.asarray(s)[0, :n_seg].astype(np.float64)
-        maxs = np.asarray(mx)[0, :n_seg].astype(np.float64)
-        maxs[np.isneginf(maxs)] = 0.0                 # empty segments
-        return sums, maxs
+        layout = _segreduce_layout(seg_ids, n_seg)
+        s, mx = _pallas_segreduce(int(seg_ids.size), n_seg)(
+            _as_device(values, jnp.float32), *layout)
+        return (np.asarray(s, dtype=np.float64),
+                np.asarray(mx, dtype=np.float64))
 
-    return device_guard(
-        "kernel.segment_reduce", "pallas", device_fn,
-        lambda: (_segment_sum_numpy(values, seg_ids, n_seg),
-                 _segment_max_numpy(values, seg_ids, n_seg)))
+    def numpy_fn():
+        vals = np.asarray(values)
+        return (_segment_sum_numpy(vals, seg_ids, n_seg),
+                _segment_max_numpy(vals, seg_ids, n_seg))
+
+    return device_guard("kernel.segment_reduce", "pallas", device_fn,
+                        numpy_fn)
 
 
 # -- public segment reductions -----------------------------------------------
@@ -605,7 +679,9 @@ def _queue_layout(posted, arrival, bounds):
 def _jax_queue_walk(depth: int):
     """Jitted lock-step Fenwick sweep: all rounds in one ``fori_loop``, no
     host round-trip between rounds.  ``depth`` (the per-round chain length)
-    is static and unrolled; shapes retrace per arena layout."""
+    is static and unrolled; shapes retrace per arena layout.  This is the
+    queue walk of both device backends: XLA lowers its per-lane gathers and
+    scatters on every device, Mosaic does not."""
     import jax
     import jax.numpy as jnp
 
@@ -644,76 +720,6 @@ def _jax_queue_walk(depth: int):
     return jax.jit(walk)
 
 
-def _queue_walk_pallas_kernel(tree_ref, b_ref, starts_ref, counts_ref,
-                              toff_ref, span_ref, steps_ref, *,
-                              depth: int, rounds: int):
-    """The same lock-step rounds as :func:`_jax_queue_walk`, fused into one
-    Pallas program: every tree/arrival array resident for the whole sweep."""
-    import jax
-    import jax.numpy as jnp
-
-    tree = tree_ref[0, :]
-    b = b_ref[0, :]
-    starts = starts_ref[0, :]
-    counts = counts_ref[0, :]
-    toff = toff_ref[0, :]
-    span = span_ref[0, :]
-    sink = tree.shape[0] - 1
-    steps0 = jnp.zeros(b.shape, dtype=tree.dtype)
-
-    def round_body(j, state):
-        tree, steps = state
-        mask = counts > j
-        s = jnp.where(mask, starts + j, 0)
-        p = jnp.where(mask, b[s] + 1, 0)
-        i = p
-        acc = jnp.zeros_like(p)
-        for _ in range(depth):
-            acc = acc + tree[toff + i]
-            i = i - (i & -i)
-        steps = steps.at[s].add(jnp.where(mask, acc, 0))
-        i = p
-        bound = jnp.where(mask, span, -1)
-        idx = jnp.where(mask, toff + i, sink)
-        delta = jnp.where(mask, -1, 0).astype(tree.dtype)
-        for _ in range(depth):
-            tree = tree.at[idx].add(delta)
-            i = i + (i & -i)
-            idx = jnp.where(i > bound, sink, toff + i)
-        return tree, steps
-
-    _, steps = jax.lax.fori_loop(0, rounds, round_body, (tree, steps0))
-    steps_ref[0, :] = steps
-
-
-@functools.cache
-def _pallas_queue_walk(n_pad: int, r_pad: int, t_pad: int, depth: int,
-                       rounds: int):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    def row(w):
-        return pl.BlockSpec((1, w), lambda i: (0, 0))
-
-    return pl.pallas_call(
-        functools.partial(_queue_walk_pallas_kernel, depth=depth,
-                          rounds=rounds),
-        grid=(1,),
-        in_specs=[row(t_pad), row(n_pad), row(r_pad), row(r_pad),
-                  row(r_pad), row(r_pad)],
-        out_specs=row(n_pad),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-        interpret=jax.default_backend() == "cpu",
-    )
-
-
-def _pad_row(a, width, fill, dtype=np.int32):
-    out = np.full((1, width), fill, dtype=dtype)
-    out[0, :a.size] = a
-    return out
-
-
 def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarray:
     """Batched receive-queue walk lengths on the chosen backend.
 
@@ -722,8 +728,9 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
     ``r`` owns slots ``bounds[r]:bounds[r+1]`` of ``posted``/``arrival``;
     returns per-arrival steps in the same layout).  The walk is integer
     work, so every backend is bit-equal to the numpy reference — the device
-    paths just run all rounds in one program instead of one host-synced
-    array pass per round.  Index arithmetic runs in int32 on device
+    path runs all rounds in one program instead of one host-synced array
+    pass per round.  ``'jax'`` and ``'pallas'`` share that program
+    (:func:`_jax_queue_walk`).  Index arithmetic runs in int32 on device
     (arenas beyond 2^31 - 1 queue slots must use numpy).  Device-backend
     failures degrade to the numpy reference via :func:`device_guard`
     (site ``kernel.queue_walk``) — bit-identically, since the walk is
@@ -751,34 +758,21 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
 
     def device_fn():
         import jax.numpy as jnp
-        if backend == "jax":
-            walk = _jax_queue_walk(depth)
-            steps = walk(jnp.asarray(tree, jnp.int32),
-                         jnp.asarray(b, jnp.int32),
-                         jnp.asarray(starts, jnp.int32),
-                         jnp.asarray(counts, jnp.int32),
-                         jnp.asarray(toff, jnp.int32),
-                         jnp.asarray(span, jnp.int32), rounds)
-            return np.asarray(steps, dtype=np.int64)
-        # pallas: pad every row to a lane multiple; padded regions have
-        # count 0 (never active) and padded chains park at the shared sink
-        def up(n):
-            return max(_LANE, -(-n // _LANE) * _LANE)
-
-        n_pad, r_pad, t_pad = up(N), up(int(counts.size)), up(int(tree.size))
-        call = _pallas_queue_walk(n_pad, r_pad, t_pad, depth, rounds)
-        steps = call(_pad_row(tree, t_pad, 0), _pad_row(b, n_pad, 0),
-                     _pad_row(starts, r_pad, 0), _pad_row(counts, r_pad, 0),
-                     _pad_row(toff, r_pad, 0), _pad_row(span, r_pad, 0))
-        return np.asarray(steps)[0, :N].astype(np.int64)
+        walk = _jax_queue_walk(depth)
+        steps = walk(jnp.asarray(tree, jnp.int32), jnp.asarray(b, jnp.int32),
+                     jnp.asarray(starts, jnp.int32),
+                     jnp.asarray(counts, jnp.int32),
+                     jnp.asarray(toff, jnp.int32),
+                     jnp.asarray(span, jnp.int32), rounds)
+        return np.asarray(steps, dtype=np.int64)
 
     return device_guard("kernel.queue_walk", backend, device_fn, numpy_fn)
 
 
 # -- deprecated one-hot era shims --------------------------------------------
 
-#: Deprecated: the retired one-hot kernel's work ceiling.  The fused
-#: scatter-accumulate kernel is O(messages), so no limit applies; the
+#: Deprecated: the retired one-hot kernel's work ceiling.  The fused tiled
+#: kernel works per (tile, chunk) pair, so no limit applies; the
 #: constant is kept (with :func:`pallas_within_limit`) so external callers
 #: written against the old reroute logic keep working.
 PALLAS_ONE_HOT_LIMIT = 1 << 24
@@ -787,9 +781,10 @@ PALLAS_ONE_HOT_LIMIT = 1 << 24
 def pallas_within_limit(n_values: int, n_seg: int) -> bool:
     """Deprecated: always True.
 
-    The one-hot Pallas kernel this guarded was replaced by the fused
-    scatter-accumulate kernel (:func:`fused_segment_reduce`), which is
-    O(messages) — there is no work ceiling and no jax reroute.  Warns once
+    The one-hot Pallas kernel this guarded was replaced by the fused tiled
+    kernel (:func:`fused_segment_reduce`), which reduces only the (segment
+    tile x message chunk) pairs that overlap — there is no work ceiling and
+    no jax reroute.  Warns once
     per process (via the resettable
     :class:`repro.comm.health.BackendHealth` registry), then delegates to
     the new behaviour (every size is within limit).
@@ -797,7 +792,7 @@ def pallas_within_limit(n_values: int, n_seg: int) -> bool:
     get_health().warn_once(
         "kernels.one_hot_deprecated",
         "pallas_within_limit/PALLAS_ONE_HOT_LIMIT are deprecated: the "
-        "one-hot kernel was replaced by a fused scatter-accumulate "
-        "kernel with no size limit; the pallas backend now handles "
+        "one-hot kernel was replaced by a fused tiled kernel with no "
+        "size limit; the pallas backend now handles "
         "every request directly", category=DeprecationWarning, stacklevel=3)
     return True

@@ -6,18 +6,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg) only exist in
-    newer jax releases than the pinned toolchain ships; when present we ask
-    for ``Auto`` on every axis (the pre-AxisType default), otherwise we omit
-    the kwarg entirely.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with ``Auto`` sharding on every axis."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._jax_compat import pcast, shard_map
-
 
 def quantize_int8(x, scale):
     q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
@@ -61,7 +59,7 @@ def dp_grads_compressed(loss_fn, params, batch, mesh,
             lambda g: jnp.zeros((n_dev,) + g.shape, jnp.float32), params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), jax.tree.map(lambda _: P(axis_name), batch),
                   jax.tree.map(lambda _: P(axis_name), errors)),
         out_specs=(P(), jax.tree.map(lambda _: P(axis_name), errors)))
@@ -70,7 +68,7 @@ def dp_grads_compressed(loss_fn, params, batch, mesh,
         # replicated input directly would insert an implicit psum (transpose
         # of replication), defeating quantize-before-reduce.
         p_local = jax.tree.map(
-            lambda a: pcast(a, (axis_name,), to="varying"), p)
+            lambda a: jax.lax.pcast(a, (axis_name,), to="varying"), p)
         g = jax.grad(loss_fn)(p_local, b)
         flat_g, td = jax.tree.flatten(g)
         flat_e, _ = jax.tree.flatten(e)

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._jax_compat import shard_map
-
 from repro.nn.config import ArchConfig
 
 
@@ -64,7 +62,7 @@ def moe_ffn_ep(x, p, cfg: ArchConfig, mesh, axis_name: str = "model"):
     # outputs after the reverse all-to-all) but the replication is not
     # statically inferable -> check_vma=False.
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(), check_vma=False,
     )

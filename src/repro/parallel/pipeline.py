@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._jax_compat import pcast, shard_map
-
 
 def stack_stages(layer_params, n_stages: int):
     """[L, ...] stacked layer params -> [S, L/S, ...] stage-major params."""
@@ -45,7 +43,7 @@ def gpipe(stage_fn, stage_params, microbatches, mesh, axis: str = "pod"):
     perm = [(i, (i + 1) % S) for i in range(S)]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P()),
         out_specs=P())
     def _run(params_local, mb):
@@ -65,8 +63,8 @@ def gpipe(stage_fn, stage_params, microbatches, mesh, axis: str = "pod"):
             outs = jnp.where(valid, outs.at[out_idx].set(y), outs)
             return y, outs
 
-        carry0 = pcast(jnp.zeros_like(mb[0]), (axis,), to="varying")
-        outs0 = pcast(jnp.zeros_like(mb), (axis,), to="varying")
+        carry0 = jax.lax.pcast(jnp.zeros_like(mb[0]), (axis,), to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(mb), (axis,), to="varying")
         _, outs = jax.lax.fori_loop(0, M + S - 1, tick, (carry0, outs0))
         # broadcast the last stage's outputs to every stage
         outs = jnp.where(is_last, outs, jnp.zeros_like(outs))

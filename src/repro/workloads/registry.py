@@ -138,7 +138,8 @@ class SweepRow:
 
 def sweep(scenarios=DEFAULT_SCENARIOS, machines=None,
           level: str = "contention", seed: int = 0,
-          validate: bool = True) -> list[SweepRow]:
+          validate: bool = True, backend: str | None = None
+          ) -> list[SweepRow]:
     """Price every scenario phase on every machine in ONE arena call.
 
     Each scenario in ``scenarios`` is derived once (seeded per the workload
@@ -149,7 +150,9 @@ def sweep(scenarios=DEFAULT_SCENARIOS, machines=None,
     :func:`default_machines`), and the whole cross product goes through a
     single :func:`repro.comm.strategies.best_strategy_many` call — the
     mixed-machine candidate set stacks per machine group inside — at model
-    ladder ``level`` with one arrival ``seed``.  Returns one
+    ladder ``level`` with one arrival ``seed`` on the stacked-pass
+    ``backend`` (as in :func:`~repro.comm.strategies.best_strategy_many`;
+    None is the session default).  Returns one
     :class:`SweepRow` per (machine, scenario, phase), machines in dict
     order, scenarios in input order; rows priced under a backend fallback
     carry ``degraded=True``.
@@ -170,7 +173,8 @@ def sweep(scenarios=DEFAULT_SCENARIOS, machines=None,
             for label, pat in phases:
                 keys.append((mname, sc.name, label, pat))
                 bound.append(pat.bind(machine))
-    verdicts = best_strategy_many(bound, seed=seed, level=level)
+    verdicts = best_strategy_many(bound, seed=seed, level=level,
+                                  backend=backend)
     return [SweepRow(machine=mname, scenario=sname, phase=label,
                      n_msgs=pat.n_msgs, total_bytes=pat.total_bytes,
                      model_winner=v.model_winner, sim_winner=v.sim_winner,

@@ -211,7 +211,7 @@ print(json.dumps(results))
 
 @pytest.fixture(scope="module")
 def mesh_results():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")    # never the parent's chip
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,

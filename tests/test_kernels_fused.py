@@ -86,6 +86,37 @@ def test_fused_reduce_empty_segment_gets_zero_not_neg_inf():
     np.testing.assert_allclose(maxs, [0.0, 0.0, 3.0, 0.0])
 
 
+def _one_heavy_segment(rng):
+    # one segment spans many message chunks
+    return rng.random(3000), np.full(3000, 130), 300
+
+
+def _sparse_segments(rng):
+    # sorted chunks straddle tiles, and whole tiles hold no segment
+    return rng.random(1500), rng.choice([0, 127, 128, 1000, 4999], 1500), 5000
+
+
+def _negative_values(rng):
+    # maxima are floored at 0, exactly as the numpy reference
+    return -rng.random(700), rng.integers(0, 40, 700), 40
+
+
+def _no_messages(rng):
+    return np.zeros(0), np.zeros(0, dtype=np.int64), 9
+
+
+@needs_jax
+@pytest.mark.parametrize("case", [_one_heavy_segment, _sparse_segments,
+                                  _negative_values, _no_messages])
+def test_fused_reduce_tiled_layouts_match_numpy(case):
+    vals, ids, n_seg = case(np.random.default_rng(5))
+    sums, maxs = cs.fused_segment_reduce(vals, ids, n_seg)
+    np.testing.assert_allclose(sums, cs.segment_sum(vals, ids, n_seg, "numpy"),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(maxs, cs.segment_max(vals, ids, n_seg, "numpy"),
+                               rtol=1e-5, atol=1e-5)
+
+
 @needs_jax
 @settings(max_examples=25, deadline=None)
 @given(st.integers(min_value=0, max_value=400),
@@ -300,5 +331,5 @@ def test_deprecated_one_hot_shim_still_importable():
     from repro.comm.health import reset_health
     assert cs.PALLAS_ONE_HOT_LIMIT == 1 << 24
     reset_health()                       # clear the warn-once registry
-    with pytest.warns(DeprecationWarning, match="fused scatter-accumulate"):
+    with pytest.warns(DeprecationWarning, match="fused tiled kernel"):
         assert cs.pallas_within_limit(1 << 30, 1 << 20) is True

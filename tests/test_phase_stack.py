@@ -363,7 +363,7 @@ def test_pallas_one_hot_shim_warns_once_and_allows_everything():
     O(messages), no reroute exists)."""
     from repro.comm.health import reset_health
     reset_health()                       # clear the warn-once registry
-    with pytest.warns(DeprecationWarning, match="fused scatter-accumulate"):
+    with pytest.warns(DeprecationWarning, match="fused tiled kernel"):
         assert _cs.pallas_within_limit(1, 1)
     with warnings.catch_warnings():
         warnings.simplefilter("error")          # a second warning would raise

@@ -20,6 +20,7 @@ Four layers of trust, weakest to strongest:
 Property tests ride the optional-hypothesis shim; every deterministic test
 is numpy-only and runs without jax.
 """
+import os
 import subprocess
 import sys
 
@@ -245,6 +246,7 @@ def test_row_parallel_ops_match_real_pspecs():
     param_pspecs tree, per arch, on a fake 8-device mesh (subprocess, so the
     main process keeps its single-device view)."""
     proc = subprocess.run([sys.executable, "-c", _PSPEC_SCRIPT],
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr
     got = dict(line.split() for line in proc.stdout.strip().splitlines())

@@ -93,3 +93,21 @@ def test_sweep_is_deterministic(rows):
 def test_scenarios_are_the_shipped_set():
     assert [sc.name for sc in DEFAULT_SCENARIOS] == \
         ["qwen3-moe-a2a", "deepseek-moe-a2a", "llama3-tp", "llama3-pipeline"]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+def test_sweep_backend_winners_match_numpy(rows, backend):
+    """``sweep(backend=...)`` reaches the stacked passes' device backends
+    and picks the numpy sweep's winners, with no fallback on the way."""
+    from repro.comm.health import get_health, reset_health
+    if backend != "numpy":
+        pytest.importorskip("jax")
+    reset_health()
+    got = sweep(backend=backend)
+    assert get_health().n_events == 0
+    assert not any(r.degraded for r in got)
+    assert [(r.model_winner, r.sim_winner) for r in got] == \
+           [(r.model_winner, r.sim_winner) for r in rows]
+    for g, w in zip(got, rows):
+        assert g.model == pytest.approx(w.model, rel=1e-4)
+        assert g.sim == pytest.approx(w.sim, rel=1e-4)
