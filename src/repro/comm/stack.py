@@ -59,7 +59,7 @@ from typing import Any
 
 import numpy as np
 
-from . import faults
+from . import faults, obs
 from .guard import ArenaOverflowError
 from .health import get_health
 from .phase import CommPhase
@@ -154,16 +154,20 @@ class PhaseStack:
                     "mixed machines: every phase in a PhaseStack must be "
                     "bound to the same machine object (rebind with "
                     "CommPhase.build / CommPattern.bind first)")
-        counts = np.asarray([ph.n_msgs for ph in phases], dtype=np.int64)
-        offsets = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
-        cat = {f: (np.concatenate([getattr(ph, f) for ph in phases])
-                   if phases else np.zeros(0))
-               for f in _ARENA_FIELDS}
-        return cls(
-            machine=machine, phases=phases, offsets=offsets,
-            n_procs=np.asarray([ph.n_procs for ph in phases], dtype=np.int64),
-            phase_id=np.repeat(np.arange(len(phases), dtype=np.int64), counts),
-            **cat)
+        with obs.span("repro.plan.arena"):
+            counts = np.asarray([ph.n_msgs for ph in phases], dtype=np.int64)
+            offsets = np.concatenate([[0], np.cumsum(counts)]).astype(
+                np.int64)
+            cat = {f: (np.concatenate([getattr(ph, f) for ph in phases])
+                       if phases else np.zeros(0))
+                   for f in _ARENA_FIELDS}
+            return cls(
+                machine=machine, phases=phases, offsets=offsets,
+                n_procs=np.asarray([ph.n_procs for ph in phases],
+                                   dtype=np.int64),
+                phase_id=np.repeat(np.arange(len(phases), dtype=np.int64),
+                                   counts),
+                **cat)
 
     @classmethod
     def build_streaming(cls, phases, chunk_msgs: int = 1 << 16) -> "PhaseStack":
@@ -434,7 +438,7 @@ class PhaseStack:
         """
         store = self._device_store
         if name not in store:
-            import jax.numpy as jnp
+            from repro.kernels.comm_stack import to_device
             faults.fail_point("stack.device_store")
             a = np.asarray(getattr(self, name))
             if a.dtype == np.float64:
@@ -445,7 +449,7 @@ class PhaseStack:
                         f"arena column {name!r} exceeds int32 range; such "
                         "arenas price on the numpy backend")
                 a = a.astype(np.int32)
-            store[name] = jnp.asarray(a)
+            store[name] = to_device(a)
         return store[name]
 
     # -- segmented reductions -----------------------------------------------
@@ -610,17 +614,19 @@ class PhaseStack:
         health = get_health()
         if health.is_quarantined(backend_name):
             return None
+        obs.count("device.calls.stack.device_store")
         try:
-            dense = faults.poison(
-                "stack.device_store",
-                self._device_cost_dense(p, node_aware, use_maxrate,
-                                        backend_name, mod, same_net))
-            mode = cs.verify_mode()
-            if mode == "finite":
-                cs._check_finite(dense)
-            elif mode == "parity":
-                cs._check_parity(dense, self._numpy_dense_for(
-                    p, node_aware, use_maxrate, same_net))
+            with obs.span("repro.device.stack.device_store"):
+                dense = faults.poison(
+                    "stack.device_store",
+                    self._device_cost_dense(p, node_aware, use_maxrate,
+                                            backend_name, mod, same_net))
+                mode = cs.verify_mode()
+                if mode == "finite":
+                    cs._check_finite(dense)
+                elif mode == "parity":
+                    cs._check_parity(dense, self._numpy_dense_for(
+                        p, node_aware, use_maxrate, same_net))
         except Exception as e:  # noqa: BLE001 - degradation catches all
             health.record_failure(backend_name, "stack.device_store", e)
             return None
@@ -640,14 +646,16 @@ class PhaseStack:
         """
         import jax.numpy as jnp
 
+        from repro.kernels.comm_stack import to_device
+
         from .xp import get_xp
         xp = get_xp(backend_name)
         m = self.machine
         proto = (self._dev("proto") if p is m.params
-                 else jnp.asarray(p.protocol_of(self.size).astype(np.int32)))
-        at = jnp.asarray(np.asarray(p.alpha, dtype=np.float32))
-        rb = jnp.asarray(np.asarray(p.Rb, dtype=np.float32))
-        rn = jnp.asarray(np.asarray(p.RN, dtype=np.float32))
+                 else to_device(p.protocol_of(self.size).astype(np.int32)))
+        at = to_device(np.asarray(p.alpha, dtype=np.float32))
+        rb = to_device(np.asarray(p.Rb, dtype=np.float32))
+        rn = to_device(np.asarray(p.RN, dtype=np.float32))
         if node_aware:
             loc = self._dev("loc")
             alpha, Rb, RN = at[loc, proto], rb[loc, proto], rn[loc, proto]
@@ -661,8 +669,7 @@ class PhaseStack:
             if p.network_locality == m.params.network_locality:
                 ppn = self._dev("active_ppn")
             else:
-                ppn = jnp.asarray(
-                    self._active_ppn_for(p).astype(np.float32))
+                ppn = to_device(self._active_ppn_for(p).astype(np.float32))
             t_msg = transport_times(self._dev("size"), alpha, Rb, RN, ppn,
                                     is_net, rails=p.n_rails, xp=xp)
         else:
@@ -775,27 +782,28 @@ class PhaseStack:
     def _compute_link_contention(self, backend):
         net_bytes = self._net_bytes
         out = np.zeros(self.n_phases)
-        sel = self.is_net & (self.torus_src != self.torus_dst)
-        if not sel.any():
-            return out, net_bytes
-        torus = self.machine.torus
-        tsrc = self.torus_src[sel]
-        pid = self.phase_id[sel]
-        midx, link = torus.route_link_ids(tsrc, self.torus_dst[sel])
-        if link.size == 0:
-            return out, net_bytes
-        w = self.size[sel][midx]
-        src_span = np.int64(max(torus.size, int(tsrc.max()) + 1))
-        link_span = np.int64(torus.link_slots)
-        if self.n_phases * int(link_span) * int(src_span) >= 2 ** 62:
-            raise ValueError(
-                "packed (phase, link, source) key would overflow int64; "
-                "split the sweep into smaller stacks")
-        key = (pid[midx] * link_span + link) * src_span + tsrc[midx]
-        uk, inv = np.unique(key, return_inverse=True)
-        per_src = np.bincount(inv, weights=w)     # bytes/(phase, link, source)
-        pair = uk // src_span                     # (phase, link) runs
-        starts = np.nonzero(np.r_[True, pair[1:] != pair[:-1]])[0]
+        with obs.span("repro.sim.routing"):
+            sel = self.is_net & (self.torus_src != self.torus_dst)
+            if not sel.any():
+                return out, net_bytes
+            torus = self.machine.torus
+            tsrc = self.torus_src[sel]
+            pid = self.phase_id[sel]
+            midx, link = torus.route_link_ids(tsrc, self.torus_dst[sel])
+            if link.size == 0:
+                return out, net_bytes
+            w = self.size[sel][midx]
+            src_span = np.int64(max(torus.size, int(tsrc.max()) + 1))
+            link_span = np.int64(torus.link_slots)
+            if self.n_phases * int(link_span) * int(src_span) >= 2 ** 62:
+                raise ValueError(
+                    "packed (phase, link, source) key would overflow int64; "
+                    "split the sweep into smaller stacks")
+            key = (pid[midx] * link_span + link) * src_span + tsrc[midx]
+            uk, inv = np.unique(key, return_inverse=True)
+            per_src = np.bincount(inv, weights=w)  # bytes/(phase, link, src)
+            pair = uk // src_span                  # (phase, link) runs
+            starts = np.nonzero(np.r_[True, pair[1:] != pair[:-1]])[0]
         backend, mod = self._resolved_backend(backend)
         if mod is None:
             totals = np.add.reduceat(per_src, starts)

@@ -72,6 +72,7 @@ import dataclasses
 
 import numpy as np
 
+from . import obs
 from .phase import CommPhase
 from .primitives import segmented_arange, sum_by_pairs
 from .stack import as_stack
@@ -514,6 +515,31 @@ def best_strategy_many(patterns, machine=None, *, strategies=None,
     if arrival not in ("random", "posted"):
         raise ValueError(f"unknown arrival regime {arrival!r}; "
                          "expected 'random' or 'posted'")
+    patterns = list(patterns)
+    stats = (_sweep_stats(patterns, machine, strategies) if obs.enabled()
+             else {})
+    with obs.span("repro.plan.sweep", **stats):
+        return _sweep(patterns, machine, strategies, level, arrival, seed,
+                      params, backend, validate)
+
+
+def _sweep_stats(patterns, machine, strategies) -> dict:
+    """The ``repro.plan.sweep`` span's stats: patterns, (pattern,
+    strategy) candidates and source messages of one sweep."""
+    candidates = 0
+    for pat in patterns:
+        m = machine if machine is not None else getattr(pat, "machine", None)
+        if strategies is not None:
+            candidates += len(strategies)
+        elif m is not None:
+            candidates += len(strategies_for(m))
+    return {"patterns": len(patterns), "candidates": candidates,
+            "messages": sum(len(pat.src) for pat in patterns)}
+
+
+def _sweep(patterns, machine, strategies, level, arrival, seed, params,
+           backend, validate) -> list[StrategyVerdict]:
+    """The body of :func:`best_strategy_many`, under its sweep span."""
     from repro.core.models import phase_cost_many
     from repro.net.simulator import simulate_many
     from .health import get_health
@@ -525,33 +551,42 @@ def best_strategy_many(patterns, machine=None, *, strategies=None,
                 raise ValueError("a CommPattern needs a machine to bind to")
             phases.append(pat.bind(machine, validate=validate))
         elif machine is not None and machine is not pat.machine:
-            phases.append(CommPhase.build(machine, pat.src, pat.dst,
-                                          pat.size, n_procs=pat.n_procs,
-                                          validate=validate))
+            with obs.span("repro.plan.bind"):
+                phases.append(CommPhase.build(machine, pat.src, pat.dst,
+                                              pat.size, n_procs=pat.n_procs,
+                                              validate=validate))
         else:
             if validate:
                 from .guard import validate_phase
                 validate_phase(pat)
             phases.append(pat)
 
-    plan_rows, spans, all_phases, all_arrivals = [], [], [], []
-    for phase in phases:
-        plans, row_spans = {}, {}
-        names = (strategies if strategies is not None
-                 else strategies_for(phase.machine))
-        for name in names:
-            plan = rewrite(phase, name)
-            rng = np.random.default_rng(seed)
-            plans[name] = plan
-            row_spans[name] = slice(len(all_phases),
-                                    len(all_phases) + plan.n_phases)
-            all_phases.extend(plan.phases)
-            all_arrivals.extend([ph.random_arrival_flat(rng)
-                                 for ph in plan.phases]
-                                if arrival == "random"
-                                else [None] * plan.n_phases)
-        plan_rows.append(plans)
-        spans.append(row_spans)
+    # rewrites first, then each candidate's arrivals from its own
+    # generator seeded with ``seed``: the same draws as one interleaved loop
+    plan_rows, spans, all_phases = [], [], []
+    with obs.span("repro.plan.rewrite"):
+        for phase in phases:
+            plans, row_spans = {}, {}
+            names = (strategies if strategies is not None
+                     else strategies_for(phase.machine))
+            for name in names:
+                plan = rewrite(phase, name)
+                plans[name] = plan
+                row_spans[name] = slice(len(all_phases),
+                                        len(all_phases) + plan.n_phases)
+                all_phases.extend(plan.phases)
+            plan_rows.append(plans)
+            spans.append(row_spans)
+    all_arrivals = []
+    with obs.span("repro.plan.arrivals"):
+        for plans in plan_rows:
+            for plan in plans.values():
+                if arrival == "random":
+                    rng = np.random.default_rng(seed)
+                    all_arrivals.extend(ph.random_arrival_flat(rng)
+                                        for ph in plan.phases)
+                else:
+                    all_arrivals.extend([None] * plan.n_phases)
 
     health = get_health()
     events_before = health.n_events
@@ -599,15 +634,16 @@ def best_strategy_many(patterns, machine=None, *, strategies=None,
                               e)
         costs, sims = _price("numpy")
 
-    degraded = health.n_events > events_before
-    out = []
-    for plans, row_spans in zip(plan_rows, spans):
-        model = {name: sum(c.total for c in costs[row_spans[name]])
-                 for name in plans}
-        sim = {name: sum(r.time for r in sims[row_spans[name]])
-               for name in plans}
-        out.append(StrategyVerdict(
-            plans=plans, model=model, sim=sim,
-            model_winner=min(model, key=model.get),
-            sim_winner=min(sim, key=sim.get), degraded=degraded))
+    with obs.span("repro.plan.verdict"):
+        degraded = health.n_events > events_before
+        out = []
+        for plans, row_spans in zip(plan_rows, spans):
+            model = {name: sum(c.total for c in costs[row_spans[name]])
+                     for name in plans}
+            sim = {name: sum(r.time for r in sims[row_spans[name]])
+                   for name in plans}
+            out.append(StrategyVerdict(
+                plans=plans, model=model, sim=sim,
+                model_winner=min(model, key=model.get),
+                sim_winner=min(sim, key=sim.get), degraded=degraded))
     return out

@@ -22,6 +22,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.comm import obs
 from repro.comm.delta import ARENA_TYPES as _ARENAS
 from repro.comm.primitives import active_senders_per_node, transport_times
 from repro.comm.stack import PhaseStack, as_stack
@@ -274,14 +275,15 @@ def phase_cost_many(phases, level: str = "contention",
     """
     if level not in MODEL_LEVELS:
         raise ValueError(f"unknown model level {level!r}")
-    if isinstance(phases, _ARENAS):
-        return _stack_costs(phases, level, params, backend=backend)
-    phases = list(phases)
-    stack = as_stack(phases)
-    if stack is None:
-        return [phase_cost_phase(ph, level=level, params=params)
-                for ph in phases]
-    return _stack_costs(stack, level, params, backend=backend)
+    with obs.span("repro.plan.model"):
+        if isinstance(phases, _ARENAS):
+            return _stack_costs(phases, level, params, backend=backend)
+        phases = list(phases)
+        stack = as_stack(phases)
+        if stack is None:
+            return [phase_cost_phase(ph, level=level, params=params)
+                    for ph in phases]
+        return _stack_costs(stack, level, params, backend=backend)
 
 
 def model_ladder_many(phases, params: CommParams | None = None,

@@ -47,6 +47,7 @@ This module imports jax lazily so that importing it — and everything in
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import os
@@ -54,7 +55,7 @@ import time
 
 import numpy as np
 
-from repro.comm import faults
+from repro.comm import faults, obs
 from repro.comm.health import get_health
 
 BACKENDS = ("numpy", "jax", "pallas", "auto")
@@ -195,19 +196,33 @@ def device_guard(site: str, backend: str, device_fn, numpy_fn):
     if health.is_quarantined(backend):
         return numpy_fn()
     try:
-        faults.fail_point(site)
-        out = faults.poison(site, device_fn())
-        mode = verify_mode()
-        if mode == "finite":
-            _check_finite(out)
-        elif mode == "parity":
-            ref = numpy_fn()
-            _check_parity(out, ref)
+        with _device_call(site):
+            faults.fail_point(site)
+            out = faults.poison(site, device_fn())
+            mode = verify_mode()
+            if mode == "finite":
+                _check_finite(out)
+            elif mode == "parity":
+                ref = numpy_fn()
+                _check_parity(out, ref)
     except Exception as e:  # noqa: BLE001 - degradation catches everything
         health.record_failure(backend, site, e)
         return numpy_fn()
     health.record_success(backend)
     return out
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _device_call(site: str):
+    """The ``repro.device.<site>`` span of one device call, counted as
+    ``device.calls.<site>``; while tracing is off, a null context and no
+    name built."""
+    if not obs.enabled():
+        return _NO_SPAN
+    obs.count("device.calls." + site)
+    return obs.span("repro.device." + site)
 
 
 # -- autotuned numpy/jax crossover -------------------------------------------
@@ -391,14 +406,43 @@ def _jax_segment_ops():
     return seg_sum, seg_max
 
 
-def _as_device(a, dtype):
+def to_device(a, dtype=None):
     """``a`` as a device array: jax arrays pass through untouched (already
-    resident), anything else is converted once."""
+    resident), anything else is shipped once, as ``dtype`` when given.
+    Every host->device ship of the planner passes here or through
+    :func:`count_shipped`, so the ``device.h2d_bytes`` counter of
+    :mod:`repro.comm.obs` is exact."""
     import jax
     import jax.numpy as jnp
     if isinstance(a, jax.Array):
         return a
-    return jnp.asarray(np.asarray(a), dtype=dtype)
+    out = jnp.asarray(np.asarray(a), dtype=dtype)
+    obs.count("device.h2d_bytes", out.nbytes)
+    return out
+
+
+def count_shipped(arrays):
+    """``arrays``, host arrays handed as they are to a jitted call, which
+    ships them itself; their bytes count toward ``device.h2d_bytes`` while
+    tracing is on."""
+    if obs.enabled():
+        obs.count("device.h2d_bytes", sum(a.nbytes for a in arrays))
+    return arrays
+
+
+def to_host(a, dtype=None) -> np.ndarray:
+    """``a`` as a numpy array, as ``dtype`` when given.  A device array is
+    copied back under the ``repro.device.sync`` span, the host blocked
+    until the device has produced it, and counted (``device.syncs``,
+    ``device.d2h_bytes``); a host array is only converted.  Every
+    device->host copy of the planner passes here."""
+    if not hasattr(a, "block_until_ready"):     # already on the host
+        return np.asarray(a, dtype=dtype)
+    with obs.span("repro.device.sync"):
+        out = np.asarray(a, dtype=dtype)
+    obs.count("device.syncs")
+    obs.count("device.d2h_bytes", a.nbytes)
+    return out
 
 
 def _size_of(a) -> int:
@@ -552,19 +596,19 @@ def fused_segment_reduce(values, seg_ids,
     Kernel failures degrade to the numpy reference pair via
     :func:`device_guard` (site ``kernel.segment_reduce``).
     """
-    seg_ids = np.asarray(seg_ids)
+    seg_ids = to_host(seg_ids)
 
     def device_fn():
         import jax.numpy as jnp
 
-        layout = _segreduce_layout(seg_ids, n_seg)
+        with obs.span("repro.kernel.layout"):
+            layout = _segreduce_layout(seg_ids, n_seg)
         s, mx = _pallas_segreduce(int(seg_ids.size), n_seg)(
-            _as_device(values, jnp.float32), *layout)
-        return (np.asarray(s, dtype=np.float64),
-                np.asarray(mx, dtype=np.float64))
+            to_device(values, jnp.float32), *count_shipped(layout))
+        return to_host(s, np.float64), to_host(mx, np.float64)
 
     def numpy_fn():
-        vals = np.asarray(values)
+        vals = to_host(values)
         return (_segment_sum_numpy(vals, seg_ids, n_seg),
                 _segment_max_numpy(vals, seg_ids, n_seg))
 
@@ -577,16 +621,15 @@ def fused_segment_reduce(values, seg_ids,
 def _segment_sum_numpy(values, seg_ids, n_seg: int) -> np.ndarray:
     """The bit-identity numpy reference for :func:`segment_sum` (also the
     degradation fallback for the device backends)."""
-    return np.bincount(np.asarray(seg_ids, dtype=np.int64),
-                       weights=np.asarray(values, dtype=np.float64),
-                       minlength=n_seg)
+    return np.bincount(to_host(seg_ids, np.int64),
+                       weights=to_host(values, np.float64), minlength=n_seg)
 
 
 def _segment_max_numpy(values, seg_ids, n_seg: int) -> np.ndarray:
     """The bit-identity numpy reference for :func:`segment_max`."""
     out = np.zeros(n_seg)
-    np.maximum.at(out, np.asarray(seg_ids, dtype=np.int64),
-                  np.asarray(values, dtype=np.float64))
+    np.maximum.at(out, to_host(seg_ids, np.int64),
+                  to_host(values, np.float64))
     return out
 
 
@@ -607,9 +650,9 @@ def segment_sum(values, seg_ids, n_seg: int,
     def device_fn():
         import jax.numpy as jnp
         seg_sum, _ = _jax_segment_ops()
-        return np.asarray(seg_sum(_as_device(values, jnp.float32),
-                                  _as_device(seg_ids, jnp.int32), n_seg),
-                          dtype=np.float64)
+        return to_host(seg_sum(to_device(values, jnp.float32),
+                               to_device(seg_ids, jnp.int32), n_seg),
+                       np.float64)
 
     return device_guard("kernel.segment_reduce", backend, device_fn,
                         lambda: _segment_sum_numpy(values, seg_ids, n_seg))
@@ -631,9 +674,9 @@ def segment_max(values, seg_ids, n_seg: int,
     def device_fn():
         import jax.numpy as jnp
         _, seg_max = _jax_segment_ops()
-        out = np.asarray(seg_max(_as_device(values, jnp.float32),
-                                 _as_device(seg_ids, jnp.int32), n_seg),
-                         dtype=np.float64)
+        out = to_host(seg_max(to_device(values, jnp.float32),
+                              to_device(seg_ids, jnp.int32), n_seg),
+                      np.float64)
         out[np.isneginf(out)] = 0.0
         return out
 
@@ -748,8 +791,9 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
     if backend == "numpy":
         return numpy_fn()
 
-    tree, b, starts, counts, toff, span, depth, rounds = _queue_layout(
-        posted, arrival, bounds)
+    with obs.span("repro.kernel.layout"):
+        tree, b, starts, counts, toff, span, depth, rounds = _queue_layout(
+            posted, arrival, bounds)
     N = int(b.size)
     if N == 0 or rounds == 0:
         return np.zeros(N, dtype=np.int64)
@@ -759,12 +803,10 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
     def device_fn():
         import jax.numpy as jnp
         walk = _jax_queue_walk(depth)
-        steps = walk(jnp.asarray(tree, jnp.int32), jnp.asarray(b, jnp.int32),
-                     jnp.asarray(starts, jnp.int32),
-                     jnp.asarray(counts, jnp.int32),
-                     jnp.asarray(toff, jnp.int32),
-                     jnp.asarray(span, jnp.int32), rounds)
-        return np.asarray(steps, dtype=np.int64)
+        steps = walk(*(to_device(a, jnp.int32)
+                       for a in (tree, b, starts, counts, toff, span)),
+                     rounds)
+        return to_host(steps, np.int64)
 
     return device_guard("kernel.queue_walk", backend, device_fn, numpy_fn)
 

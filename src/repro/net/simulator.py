@@ -29,6 +29,7 @@ import dataclasses
 import numpy as np
 
 from repro.comm import ARENA_TYPES as _ARENAS
+from repro.comm import obs
 from repro.comm import CommPhase, PhaseStack
 from repro.comm.stack import as_stack
 from repro.comm.primitives import (per_proc_sums, queue_traversal_steps,
@@ -211,23 +212,24 @@ def simulate_many(phases,
     """
     if noise > 0.0 and rng is None:
         rng = np.random.default_rng(0)
-    if isinstance(phases, _ARENAS):
-        stack = phases
-    else:
-        phases = list(phases)
-        stack = as_stack(phases)
-    if stack is not None:
-        out = _simulate_stack(stack, recv_post_orders, arrival_orders,
-                              backend=backend)
-        if noise > 0.0:
-            # same draw order as the per-phase loop, which returns early for
-            # empty phases without touching the rng
-            for r, ph in zip(out, stack.phases):
-                if ph.n_msgs:
-                    r.time *= float(np.exp(rng.normal(0.0, noise)))
-        return out
-    return [simulate(
-        ph,
-        recv_post_order=recv_post_orders[i] if recv_post_orders else None,
-        arrival_order=arrival_orders[i] if arrival_orders else None,
-        rng=rng, noise=noise) for i, ph in enumerate(phases)]
+    with obs.span("repro.plan.simulate"):
+        if isinstance(phases, _ARENAS):
+            stack = phases
+        else:
+            phases = list(phases)
+            stack = as_stack(phases)
+        if stack is not None:
+            out = _simulate_stack(stack, recv_post_orders, arrival_orders,
+                                  backend=backend)
+            if noise > 0.0:
+                # same draw order as the per-phase loop, which returns early
+                # for empty phases without touching the rng
+                for r, ph in zip(out, stack.phases):
+                    if ph.n_msgs:
+                        r.time *= float(np.exp(rng.normal(0.0, noise)))
+            return out
+        return [simulate(
+            ph,
+            recv_post_order=recv_post_orders[i] if recv_post_orders else None,
+            arrival_order=arrival_orders[i] if arrival_orders else None,
+            rng=rng, noise=noise) for i, ph in enumerate(phases)]

@@ -50,6 +50,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import itertools
 import threading
 from typing import Any
 
@@ -196,6 +197,7 @@ class StrategyService:
         self._arenas: collections.OrderedDict[str, Any] = \
             collections.OrderedDict()
         self._lock = threading.Lock()
+        self._requests = itertools.count()
         mname = getattr(machine, "name", type(machine).__name__)
         strat = ",".join(strategies) if strategies else "auto"
         self._config_token = (f"{mname}|{getattr(machine, 'n_procs', '?')}|"
@@ -252,43 +254,54 @@ class StrategyService:
         per-backend circuit breaker.  Any fallback anywhere marks the
         affected results ``degraded=True``.  Never raises.
         """
+        from repro.comm import obs
+
+        with obs.span("repro.service.query", request=next(self._requests)):
+            return self._query_many(list(patterns), timeout)
+
+    def _query_many(self, patterns, timeout) -> list[ServiceResult]:
+        """The body of :meth:`query_many`, one span per station."""
+        from repro.comm import obs
         from repro.comm.guard import PatternError, validate_phase
 
-        patterns = list(patterns)
         results: list[ServiceResult | None] = [None] * len(patterns)
         deadline = Deadline(self.timeout if timeout is _DEFAULT_TIMEOUT
                             else timeout)
         live: list[int] = []
-        for i, pat in enumerate(patterns):
-            if self.validate:
-                try:
-                    validate_phase(pat, where=f"query[{i}]")
-                except PatternError as e:
-                    results[i] = ServiceResult(verdict=None, error=e)
-                    continue
-            live.append(i)
+        with obs.span("repro.service.validate"):
+            for i, pat in enumerate(patterns):
+                if self.validate:
+                    try:
+                        validate_phase(pat, where=f"query[{i}]")
+                    except PatternError as e:
+                        results[i] = ServiceResult(verdict=None, error=e)
+                        continue
+                live.append(i)
         if not live:
             return results
 
         try:
-            self.admission.acquire(len(live), deadline)
+            with obs.span("repro.service.admit"):
+                self.admission.acquire(len(live), deadline)
         except (Overloaded, DeadlineExceeded) as e:
             for i in live:
                 results[i] = ServiceResult(verdict=None, error=e)
             return results
         try:
+            with obs.span("repro.service.key"):
+                keys = {i: self._key(patterns[i]) for i in live}
             misses: list[int] = []
-            keys: dict[int, str] = {}
-            for i in live:
-                keys[i] = self._key(patterns[i])
-                body = self.cache.get(keys[i])
-                if body is not None:
-                    results[i] = ServiceResult(
-                        verdict=_verdict_from_body(body), cached=True)
-                else:
-                    misses.append(i)
+            with obs.span("repro.service.cache"):
+                for i in live:
+                    body = self.cache.get(keys[i])
+                    if body is not None:
+                        results[i] = ServiceResult(
+                            verdict=_verdict_from_body(body), cached=True)
+                    else:
+                        misses.append(i)
             if misses:
-                self._price(patterns, misses, keys, results, deadline)
+                with obs.span("repro.service.sweep"):
+                    self._price(patterns, misses, keys, results, deadline)
         finally:
             self.admission.release(len(live))
         return results

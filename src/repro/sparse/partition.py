@@ -23,7 +23,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.comm import CommPhase, PhaseStack
+from repro.comm import CommPhase, PhaseStack, obs
 
 from .csr import CSR
 
@@ -97,9 +97,11 @@ class CommPattern:
         """Bind this pattern to a machine: returns a :class:`CommPhase` with
         locality, protocol, torus endpoints and active-sender counts cached.
         ``validate=True`` runs :meth:`validate` first."""
-        return CommPhase.build(machine, self.src, self.dst, self.size,
-                               n_procs=self.n_procs if n_procs is None else n_procs,
-                               validate=validate)
+        with obs.span("repro.plan.bind"):
+            return CommPhase.build(
+                machine, self.src, self.dst, self.size,
+                n_procs=self.n_procs if n_procs is None else n_procs,
+                validate=validate)
 
     def rewrite(self, machine, strategy: str):
         """Bind to ``machine`` and apply a node-aware strategy rewrite.

@@ -24,6 +24,7 @@ import repro.comm.delta
 import repro.comm.faults
 import repro.comm.guard
 import repro.comm.health
+import repro.comm.obs
 import repro.comm.phase
 import repro.comm.primitives
 import repro.comm.stack
@@ -47,7 +48,7 @@ MODULES = [repro.comm.phase, repro.comm.primitives, repro.comm.stack,
            repro.comm.delta, repro.comm.strategies, repro.net.machine,
            repro.workloads.moe, repro.workloads.tp, repro.workloads.pipe,
            repro.workloads.registry, repro.comm.guard, repro.comm.faults,
-           repro.comm.health, repro.serve.strategy,
+           repro.comm.health, repro.comm.obs, repro.serve.strategy,
            repro.serve.admission, repro.serve.cache,
            repro.exec.plan, repro.exec.reference, repro.exec.lower,
            repro.exec.measure, repro.exec.calibrate, repro.exec.presets]
