@@ -35,8 +35,11 @@ Spans (all named ``repro.<layer>.<what>``):
 Counters: ``device.syncs`` and ``device.d2h_bytes`` (device->host copies,
 :func:`repro.kernels.comm_stack.to_host`), ``device.h2d_bytes`` (host
 arrays shipped, :func:`repro.kernels.comm_stack.to_device` and
-``count_shipped``) and
-``device.calls.<site>`` (device calls per fault site).
+``count_shipped``),
+``device.calls.<site>`` (device calls per fault site) and
+``rewrite.fan_passes`` (the aggregated rewrites' masked fan-out passes,
+one per injector rank that only some messages reach: 0 where every node
+is full).
 
 Off (the default), :func:`span` returns one shared null context and
 :func:`count` returns at once: one global check per site, and no import

@@ -86,14 +86,23 @@ def per_proc_sums(idx, values, n: int) -> np.ndarray:
                        minlength=n)
 
 
+#: Widest packed key range, per input pair, that :func:`sum_by_pairs`
+#: groups by direct index; a wider one is sorted (sorting wins on a CPU
+#: host from about 4-8 keys a pair).
+_DENSE_KEYS_PER_PAIR = 4
+
+
 def sum_by_pairs(a, b, w) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Aggregate weights ``w`` over distinct ``(a, b)`` pairs.
 
     Returns ``(ua, ub, sums)`` sorted by ``(a, b)``; ``sums[i]`` is the total
-    weight of pair ``(ua[i], ub[i])``.  This is the engine's one aggregation
-    idiom (``np.unique`` on a packed key + ``bincount`` on the inverse) — the
-    strategy rewrites build every gather/inter/scatter message set with it.
-    ``a`` and ``b`` must be non-negative integers.
+    weight of pair ``(ua[i], ub[i])``, added in array order.  The pairs are
+    packed into one key; where the key range is at most
+    ``_DENSE_KEYS_PER_PAIR`` times the input, ``bincount`` on the key itself
+    groups them (node pairs, self-pairs), else ``np.unique`` sorts the key
+    and ``bincount`` sums on its inverse.  Both add the same terms in the
+    same order: the result does not depend on the path.  ``a`` and ``b``
+    must be non-negative integers.
     """
     a = np.asarray(a, dtype=np.int64)
     b = np.asarray(b, dtype=np.int64)
@@ -101,8 +110,13 @@ def sum_by_pairs(a, b, w) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     if a.size == 0:
         return a, b, w
     span = np.int64(b.max()) + 1
-    uk, inv = np.unique(a * span + b, return_inverse=True)
-    sums = np.bincount(inv, weights=w)
+    key = a * span + b
+    if int(key.max()) < _DENSE_KEYS_PER_PAIR * a.size:
+        uk = np.flatnonzero(np.bincount(key))
+        sums = np.bincount(key, weights=w)[uk]
+    else:
+        uk, inv = np.unique(key, return_inverse=True)
+        sums = np.bincount(inv, weights=w)
     return (uk // span).astype(np.int64), (uk % span).astype(np.int64), sums
 
 

@@ -54,12 +54,17 @@ carry the staging cost.  ``strategies_for(machine)`` returns the sweep set a
 machine supports (the GPU pair requires device endpoints and the staged rate
 classes); ``best_strategy``/``best_strategy_many`` default to it.
 
-All rewrites are built from the engine's ``np.unique``/``bincount`` idiom
-(:func:`repro.comm.primitives.sum_by_pairs`,
-:func:`repro.comm.primitives.segmented_arange`) — no per-message Python
-loops.  "Off-node" means the sender's and receiver's *nodes* differ, which
-coincides with the machine's network locality classes on both shipped
-machines (Blue Waters and TPU v5e).
+All rewrites are array passes, with no per-message Python loop.  Pair
+sums (the inter phase's node pairs, the staging copies, ``device_direct``)
+come from :func:`repro.comm.primitives.sum_by_pairs`, which groups by
+direct index where the pair keys are dense and sorts them otherwise.  The
+k-way fan-out of the gather and scatter phases is summed per (endpoint,
+injector rank) by ``bincount`` over the endpoint (:func:`_fan_sums`): a
+message is never expanded into its ``k`` shares, and the sums are
+bit-identical to summing the expanded shares.  "Off-node" means the
+sender's and receiver's *nodes* differ, which coincides with the machine's
+network locality classes on both shipped machines (Blue Waters and TPU
+v5e).
 
 Layering: the rewrites are numpy-only and sit below both consumers, like the
 rest of :mod:`repro.comm`.  :func:`best_strategy` is the one function that
@@ -247,6 +252,40 @@ def host_staged(phase: CommPhase) -> StrategyPlan:
     return _aggregated(phase, "host_staged", split=True, staged=True)
 
 
+def _fan_sums(end, share, k) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """One side of an aggregated rewrite's fan-out, without expanding
+    messages into shares.
+
+    Message ``i`` sends an equal ``share[i]`` to (gather) or from (scatter)
+    each injector rank ``r < k[i]`` of ``end[i]``'s node.  Returns
+    ``(ends, ranks, sums)``, one row per (endpoint, rank) that some message
+    reaches, by endpoint and then rank; ``sums`` adds each row's shares in
+    message order, the same terms in the same order as summing the
+    expanded shares, so the result is bit-identical to that.  Every
+    message reaches the ranks ``r < k.min()``: they share one pass over
+    the messages.  Each rank above that takes one masked pass, counted as
+    ``rewrite.fan_passes`` (nonzero only where ``k`` varies, as on a
+    partially filled node).
+    """
+    present = np.bincount(end) > 0
+    ends = np.flatnonzero(present)
+    row = (np.cumsum(present) - 1)[end]          # endpoint -> row
+    kmin, kmax = int(k.min()), int(k.max())
+    sums = np.empty((ends.size, kmax))
+    hit = np.ones((ends.size, kmax), dtype=bool)
+    sums[:, :kmin] = np.bincount(row, weights=share,
+                                 minlength=ends.size)[:, None]
+    for r in range(kmin, kmax):
+        on = k > r
+        reached = row[on]
+        hit[:, r] = np.bincount(reached, minlength=ends.size) > 0
+        sums[:, r] = np.bincount(reached, weights=share[on],
+                                 minlength=ends.size)
+    obs.count("rewrite.fan_passes", kmax - kmin)
+    at, rank = np.nonzero(hit)
+    return ends[at], rank, sums[at, rank]
+
+
 def _aggregated(phase: CommPhase, name: str, split: bool,
                 staged: bool = False) -> StrategyPlan:
     m, P = phase.machine, phase.n_procs
@@ -275,16 +314,15 @@ def _aggregated(phase: CommPhase, name: str, split: bool,
         k = np.minimum(_avail(m, rsn, P), _avail(m, rdn, P))
     else:
         k = np.ones(rs.size, dtype=np.int64)
-    rep = np.repeat(np.arange(rs.size), k)      # message id of each share
-    rank = segmented_arange(k)                  # injector rank of each share
-    share = rsz[rep] / k[rep]
+    share = rsz / k
 
     # gather: origin -> the k injector ranks on its own node (equal shares;
-    # the share an injector originates itself needs no message)
-    g_src, g_dst = rs[rep], rsn[rep] * ppn + rank
+    # the share an injector originates itself needs no message).  Rows come
+    # out by origin, then injector: sorted by (src, dst).
+    g_src, rank, g_size = _fan_sums(rs, share, k)
+    g_dst = np.asarray(m.node_of(g_src), dtype=np.int64) * ppn + rank
     keep = g_src != g_dst
-    parts.append(("gather", *sum_by_pairs(g_src[keep], g_dst[keep],
-                                          share[keep])))
+    parts.append(("gather", g_src[keep], g_dst[keep], g_size[keep]))
 
     # inter: aggregate payload per (send node, recv node), then one message
     # per injector rank r: (S, r) -> (D, r)
@@ -299,11 +337,13 @@ def _aggregated(phase: CommPhase, name: str, split: bool,
                   B[prep] / kp[prep], inter_loc))
 
     # scatter: the k receiving ranks on the destination node forward each
-    # final destination its shares (a rank's own share needs no message)
-    s_src, s_dst = rdn[rep] * ppn + rank, rd[rep]
-    keep = s_src != s_dst
-    parts.append(("scatter", *sum_by_pairs(s_src[keep], s_dst[keep],
-                                           share[keep])))
+    # final destination its shares (a rank's own share needs no message),
+    # sorted by (src, dst) like every other aggregated phase
+    s_dst, rank, s_size = _fan_sums(rd, share, k)
+    s_src = np.asarray(m.node_of(s_dst), dtype=np.int64) * ppn + rank
+    keep = np.flatnonzero(s_src != s_dst)
+    keep = keep[np.argsort(s_src[keep] * P + s_dst[keep])]
+    parts.append(("scatter", s_src[keep], s_dst[keep], s_size[keep]))
 
     if staged:
         parts.append(("h2d", *sum_by_pairs(rd, rd, rsz), h2d))

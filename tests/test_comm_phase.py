@@ -8,7 +8,9 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.comm import (CommPhase, active_senders_per_node,
-                        queue_traversal_steps, batched_queue_traversal_steps)
+                        queue_traversal_steps, batched_queue_traversal_steps,
+                        sum_by_pairs)
+from repro.comm import primitives
 from repro.core import phase_cost, phase_cost_many, model_ladder, model_ladder_many
 from repro.core.topology import TorusTopology
 from repro.net import (blue_waters_machine, tpu_v5e_machine, simulate,
@@ -165,6 +167,65 @@ def test_active_senders_matches_dict_of_sets():
 def test_active_senders_no_net():
     assert (active_senders_per_node([1, 2], [0, 0], [False, False]) == 1).all()
     assert active_senders_per_node([], [], []).size == 0
+
+
+# ------------------------------------------------- pair sums ----------------
+def _pairs_input(case):
+    """``(a, b, w)`` with many repeated pairs and weights over many decades
+    (another order of addition would change the sums' low bits).
+    ``at_threshold`` and ``above_threshold`` put the packed key range at the
+    densest-path limit and one key past it; ``wide`` is far past it."""
+    rng = np.random.default_rng(8)
+    if case == "empty":
+        z = np.zeros(0, dtype=np.int64)
+        return z, z, np.zeros(0)
+    n = 400
+    hi = {"dense": n // 3, "at_threshold": primitives._DENSE_KEYS_PER_PAIR * n,
+          "above_threshold": primitives._DENSE_KEYS_PER_PAIR * n + 1,
+          "wide": 1000 * n}[case]
+    key = rng.integers(0, hi, n)
+    key[:2] = hi - 1, 9                 # the range's top; 9 fixes b's span
+    w = rng.random(n) * 10.0 ** rng.integers(-3, 9, n)
+    w[::7] = 0.0
+    return key // 10, key % 10, w
+
+
+@pytest.mark.parametrize("path", ["dense", "sorted", "chosen"])
+@pytest.mark.parametrize("case", ["empty", "dense", "at_threshold",
+                                  "above_threshold", "wide"])
+def test_sum_by_pairs_paths_are_bit_identical(monkeypatch, case, path):
+    """Grouping by direct index and by sorting give the same pairs and the
+    same sums, bit for bit; the chosen path follows the key range."""
+    a, b, w = _pairs_input(case)
+    want = None
+    if a.size:
+        span = np.int64(b.max()) + 1
+        uk, inv = np.unique(a * span + b, return_inverse=True)
+        want = (uk // span, uk % span, np.bincount(inv, weights=w))
+    sorts = []
+    if path == "dense":
+        monkeypatch.setattr(primitives, "_DENSE_KEYS_PER_PAIR", 10 ** 9)
+    elif path == "sorted":
+        monkeypatch.setattr(primitives, "_DENSE_KEYS_PER_PAIR", 0)
+    else:
+        unique = np.unique
+
+        def counted(*args, **kw):
+            sorts.append(args)
+            return unique(*args, **kw)
+        monkeypatch.setattr(np, "unique", counted)
+    got = sum_by_pairs(a, b, w)
+    if want is None:
+        assert [g.size for g in got] == [0, 0, 0]
+        assert [g.dtype for g in got] == [np.int64, np.int64, np.float64]
+        return
+    for g, x in zip(got, want):
+        assert g.dtype == x.dtype
+        np.testing.assert_array_equal(g, x)
+    np.testing.assert_array_equal(got[2].view(np.uint64),
+                                  want[2].view(np.uint64))
+    if path == "chosen":
+        assert bool(sorts) == (case in ("above_threshold", "wide"))
 
 
 # ------------------------------------------------- CommPhase caching --------

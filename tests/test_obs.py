@@ -14,7 +14,7 @@ import threading
 import numpy as np
 import pytest
 
-from repro.comm import obs
+from repro.comm import CommPhase, obs, rewrite
 from repro.comm.strategies import best_strategy_many
 from repro.kernels import comm_stack as cs
 from repro.net import blue_waters_machine
@@ -150,6 +150,29 @@ def test_to_host_of_a_host_array_is_no_sync():
     out = cs.to_host(a, np.int64)
     assert out.dtype == np.int64 and (out == a).all()
     assert obs.counters() == {}
+
+
+@pytest.mark.parametrize("strategy,short,passes", [
+    ("three_step", 0, 0), ("three_step", 5, 2 * 5), ("two_step", 5, 0)])
+def test_rewrite_counts_its_masked_fan_out_passes(strategy, short, passes):
+    """``rewrite.fan_passes``: 0 where every node is full (one shared pass
+    serves every injector rank) or one share a message; with the last of
+    8 nodes ``short`` ranks short, its ``16 - short`` shares leave
+    ``short`` ranks that only some messages reach, one masked pass each
+    on the gather and on the scatter side.  Nothing is counted off."""
+    m = blue_waters_machine((2, 2, 1))          # 8 nodes of 16 ranks
+    P = m.n_procs - short
+    rng = np.random.default_rng(4)
+    src, dst = rng.integers(0, P, 600), rng.integers(0, P, 600)
+    keep = src != dst
+    phase = CommPhase.build(m, src[keep], dst[keep],
+                            rng.integers(8, 4096, 600)[keep].astype(float),
+                            n_procs=P)
+    rewrite(phase, strategy)
+    assert obs.counters() == {}
+    obs.enable()
+    rewrite(phase, strategy)
+    assert obs.counters() == {"rewrite.fan_passes": passes}
 
 
 # -- on ----------------------------------------------------------------------

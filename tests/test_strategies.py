@@ -7,8 +7,10 @@ Three layers of certification:
   payload (so a rewrite can neither drop, duplicate, nor misroute bytes);
   the power-of-two variant makes the per-destination check a *pairwise*
   certificate (sums of distinct powers of two decode uniquely);
-* **equivalence** — the vectorized np.unique/bincount rewrites match a
-  deliberately scalar dict-based reference, message for message;
+* **equivalence** — the vectorized rewrites match a deliberately scalar
+  dict-based reference, message for message, and the aggregated rewrites
+  are bit-identical to expanding every message into its ``k`` shares and
+  summing them by sorting;
 * **golden crossover** — on a fixed AMG level the model ladder must predict
   an aggregated winner and the simulator must agree (the NAPSpMV result the
   example prints).
@@ -22,6 +24,7 @@ from repro.comm import (CommPhase, STRATEGIES, best_strategy,
 from repro.core import phase_cost_many, sequence_cost
 from repro.net import (blue_waters_machine, tpu_v5e_machine, simulate_many,
                        simulate_sequence)
+from repro.net.machine import lassen_machine
 from repro.sparse import (RowPartition, build_hierarchy, elasticity_like_3d,
                           spmv_comm_pattern)
 
@@ -138,6 +141,126 @@ def test_two_step_matches_scalar_reference(machine):
             # comparison against the aggregating reference
             got[(int(s), int(d))] = got.get((int(s), int(d)), 0.0) + float(z)
         assert got == pytest.approx(ref[role]), role
+
+
+def _pair_sums_sorted(a, b, w):
+    """Pair sums by sorting the packed key: ``np.unique``, then
+    ``bincount`` on its inverse."""
+    if a.size == 0:
+        return a, b, w
+    span = np.int64(b.max()) + 1
+    uk, inv = np.unique(a * span + b, return_inverse=True)
+    return uk // span, uk % span, np.bincount(inv, weights=w)
+
+
+def _expanded_rewrite(phase, split, staged=False):
+    """The aggregated rewrites by expansion: every remote message repeated
+    into its ``k`` shares, each phase's pairs summed by sorting.  Returns
+    ``(role, src, dst, size, loc)`` per phase, as ``rewrite`` builds them."""
+    m, P = phase.machine, phase.n_procs
+    ppn = np.int64(m.procs_per_node)
+    remote = phase.send_node != phase.dst // ppn
+
+    def avail(nodes):
+        return np.minimum(ppn, P - nodes * ppn)
+
+    parts = [("local", phase.src[~remote], phase.dst[~remote],
+              phase.size[~remote], None)]
+    rs, rd, rsz = phase.src[remote], phase.dst[remote], phase.size[remote]
+    rsn, rdn = rs // ppn, rd // ppn
+    inter_loc = h2d = None
+    if staged:
+        h2d = m.params.class_index("h2d")
+        inter_loc = m.params.class_index("host_staged")
+        parts.append(("d2h", *_pair_sums_sorted(rs, rs, rsz), h2d))
+    k = (np.minimum(avail(rsn), avail(rdn)) if split
+         else np.ones(rs.size, dtype=np.int64))
+    rep = np.repeat(np.arange(rs.size), k)
+    rank = segmented_arange(k)
+    share = rsz[rep] / k[rep]
+    g_src, g_dst = rs[rep], rsn[rep] * ppn + rank
+    keep = g_src != g_dst
+    parts.append(("gather", *_pair_sums_sorted(g_src[keep], g_dst[keep],
+                                               share[keep]), None))
+    Sn, Dn, B = _pair_sums_sorted(rsn, rdn, rsz)
+    kp = (np.minimum(avail(Sn), avail(Dn)) if split
+          else np.ones(Sn.size, dtype=np.int64))
+    prep = np.repeat(np.arange(Sn.size), kp)
+    prank = segmented_arange(kp)
+    parts.append(("inter", Sn[prep] * ppn + prank, Dn[prep] * ppn + prank,
+                  B[prep] / kp[prep], inter_loc))
+    s_src, s_dst = rdn[rep] * ppn + rank, rd[rep]
+    keep = s_src != s_dst
+    parts.append(("scatter", *_pair_sums_sorted(s_src[keep], s_dst[keep],
+                                                share[keep]), None))
+    if staged:
+        parts.append(("h2d", *_pair_sums_sorted(rd, rd, rsz), h2d))
+    out = []
+    for role, src, dst, size, loc in parts:
+        if len(src):
+            ph = CommPhase.build(m, src, dst, size, n_procs=P, loc=loc)
+            out.append((role, ph.src, ph.dst, ph.size, ph.loc))
+    return out
+
+
+def _fan_out_phase(case, seed):
+    """A random phase for the bit-identity test.  ``partial`` leaves the
+    last node part-filled, so the number of shares varies by message.
+    ``zero_sizes`` adds zero-byte messages, messages sent off node by
+    injector ranks (every node's first ranks), and a rank whose only
+    messages to and from full nodes are empty: its fan-out rows above the
+    part-filled node's share count carry 0 bytes and must still exist."""
+    machine = (lassen_machine() if case.startswith("lassen")
+               else blue_waters_machine((2, 2, 1)))
+    ppn = machine.procs_per_node
+    P = machine.n_procs - (ppn // 2 + 1 if "partial" in case else 0)
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, P, 1500)
+    dst = rng.integers(0, P, 1500)
+    # sizes over many decades, so that another order of addition would
+    # change the sums' low bits
+    size = rng.random(1500) * 10.0 ** rng.integers(0, 7, 1500)
+    if case.endswith("zero_sizes"):
+        size[::5] = 0.0
+        quiet, peer = ppn + 1, 3 * ppn + 2       # both on full nodes
+        alone = (src != quiet) & (dst != quiet)
+        inj = np.arange(0, P, ppn) + seed % 2
+        src = np.concatenate([src[alone], inj, [quiet, quiet, peer, P - 1]])
+        dst = np.concatenate([dst[alone], (inj + ppn) % P,
+                              [peer, P - 1, quiet, quiet]])
+        size = np.concatenate([size[alone], np.zeros(inj.size),
+                               [0.0, 7.0, 0.0, 5.0]])
+    keep = src != dst
+    return CommPhase.build(machine, src[keep], dst[keep], size[keep],
+                           n_procs=P)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("case", ["blue_waters_full", "blue_waters_partial",
+                                  "lassen_zero_sizes",
+                                  "lassen_partial_zero_sizes"])
+def test_aggregated_rewrites_are_bit_identical_to_share_expansion(case,
+                                                                  seed):
+    """``two_step``, ``three_step`` and ``host_staged`` sum each fan-out
+    row from the messages directly; every phase matches the expanded
+    shares summed by sorting, bit for bit."""
+    phase = _fan_out_phase(case, seed)
+    cases = [("two_step", False, False), ("three_step", True, False)]
+    if case.startswith("lassen"):
+        cases.append(("host_staged", True, True))
+    for name, split, staged in cases:
+        plan = rewrite(phase, name)
+        want = _expanded_rewrite(phase, split, staged)
+        assert plan.roles == tuple(w[0] for w in want), name
+        for ph, (role, src, dst, size, loc) in zip(plan.phases, want):
+            np.testing.assert_array_equal(ph.src, src, err_msg=role)
+            np.testing.assert_array_equal(ph.dst, dst, err_msg=role)
+            np.testing.assert_array_equal(ph.size, size, err_msg=role)
+            # bit for bit: no reordered addition, no -0.0 for 0.0
+            np.testing.assert_array_equal(ph.size.view(np.uint64),
+                                          size.view(np.uint64),
+                                          err_msg=role)
+            np.testing.assert_array_equal(ph.loc, loc, err_msg=role)
 
 
 def test_two_step_reduces_inter_node_msgs_clustered():
