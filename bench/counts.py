@@ -1,4 +1,5 @@
-"""Bytes that a kernel's work needs, from the arena's sizes.
+"""Bytes that a kernel's work needs, from the arena's sizes or the
+exchange's messages.
 
 Counted from what the reduction must read and write, not from how the
 kernel tiles it, so a kernel that does more than the work needs reads
@@ -7,6 +8,7 @@ below its roofline.
 from __future__ import annotations
 
 import json
+import math
 import pathlib
 
 PEAKS = pathlib.Path(__file__).resolve().parent / "peaks.json"
@@ -55,3 +57,34 @@ def roofline_seconds(work: dict, peak: dict) -> float:
     bfloat16 peak does not apply to it.
     """
     return work["bytes"] / peak["hbm_bytes_per_s"]
+
+
+def exchange_work(messages, n_ranks: int, unit_bytes: float) -> dict:
+    """The exchange's work per chip, from its messages alone.
+
+    ``messages`` holds one ``(src, dst, size)`` set per phase (dispatch,
+    combine).  Each message carries ``max(1, ceil(size / unit_bytes))``
+    int32 words: its sender reads each word once from HBM and sends it
+    once over ICI, and its receiver writes each word once to HBM.  Counted
+    from the messages and not from a schedule's rounds, so a plan that
+    relays words through a middle chip does more than this work, and reads
+    lower on its roofline.
+    """
+    sent = [0.0] * n_ranks
+    received = [0.0] * n_ranks
+    for src, dst, size in messages:
+        words = [max(1, math.ceil(float(z) / unit_bytes)) for z in size]
+        for s, d, w in zip(src, dst, words):
+            sent[int(s)] += w
+            received[int(d)] += w
+    return {"hbm_bytes": [4.0 * (s + r) for s, r in zip(sent, received)],
+            "ici_bytes": [4.0 * s for s in sent]}
+
+
+def exchange_seconds(work: dict, peak: dict) -> float:
+    """The least time of the exchange's ``work``: on the chip where it is
+    largest, the larger of its HBM bytes at the HBM bandwidth and its ICI
+    egress at the chip's whole published interconnect rate."""
+    ici = peak["ici_bits_per_s"] / 8.0
+    return max(max(h / peak["hbm_bytes_per_s"], i / ici)
+               for h, i in zip(work["hbm_bytes"], work["ici_bytes"]))

@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from bench import deploy, moe
+from bench import counts, deploy, moe
 
 PHASES = ("dispatch", "combine")
 
@@ -107,7 +107,11 @@ class Cell:
         self.programs = []
 
     def work(self) -> dict:
-        return {}
+        """The messages' bytes per chip (:func:`bench.counts.exchange_work`),
+        whatever plan carries them."""
+        return counts.exchange_work(self.messages,
+                                    int(self.cfg["expert_parallel"]),
+                                    float(self.mix["unit_bytes"]))
 
     def check(self, control: bool = False) -> list[tuple[str, float, float]]:
         """Every kept exchange's delivered payload against the messages:

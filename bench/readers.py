@@ -4,8 +4,9 @@ Each reader in ``bench/metrics`` takes the run's record: ``units`` (whole
 units of work in the window), ``compiles`` (backend compilations in it),
 ``cache`` (the service's cache counters, where the traffic has a
 service), ``trace`` (the reduction of :mod:`bench.trace`), ``work`` (the
-arena's sizes) and ``device_kind``.  A reader that finds nothing to read
-returns None, and the metric is left out of the line.
+arena's sizes, or the exchange's bytes per chip) and ``device_kind``.  A
+reader that finds nothing to read returns None, and the metric is left out
+of the line.
 """
 from __future__ import annotations
 
@@ -51,6 +52,21 @@ def segreduce_roofline(rec) -> float | None:
     least = counts.roofline_seconds(counts.segreduce_work(rec["work"]),
                                     counts.peaks(rec["device_kind"]))
     return 100.0 * least / (ms * 1e-3)
+
+
+def exchange_roofline(rec) -> float | None:
+    """Least time of one exchange (dispatch and combine) over the busiest
+    chip's busy time per exchange, in %: the window runs only the
+    exchange's two programs."""
+    t = rec.get("trace")
+    if t is None or not rec["units"] or not rec.get("work"):
+        return None
+    busy = max(t["busy_s"]) / rec["units"]
+    if busy <= 0:
+        return None
+    least = counts.exchange_seconds(rec["work"],
+                                    counts.peaks(rec["device_kind"]))
+    return 100.0 * least / busy
 
 
 def compiles(rec) -> float:

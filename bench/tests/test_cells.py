@@ -31,20 +31,10 @@ from bench.kinds import exchange as exchange_kind  # noqa: E402
 
 SECONDS = 0.5
 
-#: The four-chip exchange, a cell that BENCHMARK.json does not name: its
-#: files are kept, and run here, for the benchmark that measures it.
-EXCHANGE = {"workloads": [{"name": "moe-exchange-4chip",
-                           "config": "deepseek_moe16b_ep4_v5e",
-                           "traffic": "exchange", "chips": 4}],
-            "end_to_end": [{"name": "setup_s", "unit": "s"},
-                           {"name": "exchange_ms", "unit": "ms"}],
-            "per_layer": []}
-
 
 def tiny(name: str) -> dict:
     """The cell ``name`` at a size a test run can hold."""
-    spec = EXCHANGE if name == "moe-exchange-4chip" else None
-    c = copy.deepcopy(run.cell(name, spec))
+    c = copy.deepcopy(run.cell(name))
     cfg, mix = c["config"], c["mix"]
     if name == "amg-sweep":
         cfg["machine"]["args"]["torus_dims"] = [2, 2, 1]
@@ -77,7 +67,8 @@ def test_cell_is_correct(name):
     r = run_tiny(name)
     assert r["correct"], r["checks"]
     assert r["attempted"] >= 1 and r["failed"] == 0
-    assert set(r["metrics"]) >= {"setup_s"}
+    assert set(r["metrics"]) == {m["name"] for m in tiny(name)["end_to_end"]}
+    assert "setup_s" in r["metrics"]
 
 
 def _altered_verdict(monkeypatch):
@@ -130,7 +121,29 @@ def _altered_word(monkeypatch):
     monkeypatch.setattr(exchange_kind.Cell, "release", release)
 
 
-@pytest.mark.parametrize("fault", (_no_exchange, _altered_word))
+def _half_the_rounds(monkeypatch):
+    """The lowered program runs only the first half of its rounds."""
+    import dataclasses
+
+    from repro import exec as exec_
+    real = exec_.build_schedule
+
+    def half(*a, **kw):
+        sched = real(*a, **kw)
+        keep = max(1, sched.n_rounds // 2)
+        phases = []
+        for ph in sched.phases:
+            rounds = ph.rounds[:keep]
+            keep -= len(rounds)
+            if rounds:
+                phases.append(dataclasses.replace(ph, rounds=rounds))
+        return dataclasses.replace(sched, phases=tuple(phases))
+
+    monkeypatch.setattr(exec_, "build_schedule", half)
+
+
+@pytest.mark.parametrize("fault", (_no_exchange, _altered_word,
+                                   _half_the_rounds))
 def test_exchange_fault_fails(monkeypatch, fault):
     fault(monkeypatch)
     assert not run_tiny("moe-exchange-4chip")["correct"]
@@ -228,10 +241,47 @@ def test_readers_on_a_record():
     least = work["bytes"] / 819e9
     assert read("segreduce_roofline.sweep") == pytest.approx(
         100 * least / 0.1)
+    assert read("compiles.exchange") == 0.0
+    # dispatch 0->1 (8 B, 2 words), 0->2 (10 B, 3), 3->0 (1 B, 1), and
+    # combine reversed, at 4 bytes a word
+    src, dst, size = np.array([0, 0, 3]), np.array([1, 2, 0]), [8.0, 10, 1]
+    rec["work"] = counts.exchange_work([(src, dst, size), (dst, src, size)],
+                                       4, 4.0)
+    assert rec["work"] == {"hbm_bytes": [48.0, 16.0, 24.0, 8.0],
+                           "ici_bytes": [24.0, 8.0, 12.0, 4.0]}
+    least = max(48 / 819e9, 24 / 200e9)
+    # busiest chip 0.4 s over 2 exchanges
+    assert read("exchange_roofline.exchange") == pytest.approx(
+        100 * least / 0.2)
     rec["trace"] = None
     assert read("segreduce_ms.sweep") is None
+    assert read("exchange_roofline.exchange") is None
     with pytest.raises(KeyError):
         counts.peaks("TPU v9 imaginary")
+
+
+def test_exchange_work_is_the_messages():
+    """A plan that relays words through a middle chip moves more of them
+    than the messages carry; the roofline's work is the messages', which
+    the plain plan moves once each over ICI."""
+    from repro.comm import CommPhase
+    from repro.exec import build_schedule
+
+    from bench import counts, deploy, moe
+    c = tiny("moe-exchange-4chip")
+    cfg, mix = c["config"], c["mix"]
+    n, unit = int(cfg["expert_parallel"]), float(mix["unit_bytes"])
+    machine = deploy.machine(cfg["machine"])
+    messages = moe.draw(cfg, mix["tokens_per_rank"], mix["routing_seed"])
+    moved = {strategy: sum(
+        int(ph.msg_units.sum()) for s, d, z in messages
+        for ph in build_schedule(CommPhase.build(machine, s, d, z,
+                                                 n_procs=n), strategy,
+                                 unit_bytes=unit).phases)
+        for strategy in ("standard", "three_step")}
+    assert moved["three_step"] > moved["standard"]
+    work = counts.exchange_work(messages, n, unit)
+    assert sum(work["ici_bytes"]) == 4 * moved["standard"]
 
 
 def test_trace_reduction_selftest():
