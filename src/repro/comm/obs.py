@@ -30,6 +30,9 @@ Spans (all named ``repro.<layer>.<what>``):
                                 ``request``, the service's sequence number)
 ``repro.service.<station>``     ``validate``, ``admit``, ``key``, ``cache``
                                 and ``sweep``: one request station each
+``repro.workload.route``        one MoE routing decision of every token
+                                (stats ``tokens``, ``groups``)
+``repro.workload.lower``        one MoE routing lowered to its exchange
 ==============================  ============================================
 
 Counters: ``device.syncs`` and ``device.d2h_bytes`` (device->host copies,
@@ -39,7 +42,10 @@ arrays shipped, :func:`repro.kernels.comm_stack.to_device` and
 ``device.calls.<site>`` (device calls per fault site) and
 ``rewrite.fan_passes`` (the aggregated rewrites' masked fan-out passes,
 one per injector rank that only some messages reach: 0 where every node
-is full).
+is full), and per MoE lowering ``moe.expert_copies`` (off-rank (token,
+expert) assignments), ``moe.token_copies`` (token copies the dispatch
+carries: fewer where a token goes once per rank) and ``moe.dropped``
+(assignments lost to capacity).
 
 Off (the default), :func:`span` returns one shared null context and
 :func:`count` returns at once: one global check per site, and no import

@@ -20,11 +20,19 @@ _MODULES = {
 
 ARCH_IDS = tuple(_MODULES)
 
+#: Configs whose MoE traffic the workloads derive but whose model the LLM
+#: stack cannot build (deepseek-v3's MLA attention): ``get_config`` only.
+_TRAFFIC_MODULES = {
+    "deepseek-v3": "deepseek_v3",
+}
+
 
 def _mod(arch: str):
-    if arch not in _MODULES:
-        raise KeyError(f"unknown arch {arch!r}; known: {list(_MODULES)}")
-    return importlib.import_module(f"repro.configs.{_MODULES[arch]}")
+    name = _MODULES.get(arch) or _TRAFFIC_MODULES.get(arch)
+    if name is None:
+        raise KeyError(f"unknown arch {arch!r}; known: "
+                       f"{list(_MODULES) + list(_TRAFFIC_MODULES)}")
+    return importlib.import_module(f"repro.configs.{name}")
 
 
 def get_config(arch: str) -> ArchConfig:

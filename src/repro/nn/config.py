@@ -32,6 +32,12 @@ class ArchConfig:
     moe_d_ff: int = 0           # per-expert hidden size
     first_dense_layers: int = 0  # deepseek: leading dense layers
     capacity_factor: float = 1.25
+    # router: scores softmax | sigmoid; node-limited routing picks experts
+    # only inside the ``topk_group`` of ``n_group`` expert groups with the
+    # highest sum of their top-2 scores (deepseek-v3; 1 of 1: ungrouped)
+    scoring_func: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
 
     # SSM (mamba2 SSD)
     ssm_state: int = 0

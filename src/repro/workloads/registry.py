@@ -1,12 +1,14 @@
 """Scenario registry: config × workload × machine, priced in one arena.
 
 A :class:`Scenario` names one traffic shape the in-repo LLM stack emits —
-an MoE expert-parallel all-to-all (:mod:`repro.workloads.moe`), a TP
+an MoE expert-parallel all-to-all (:mod:`repro.workloads.moe`), one copy
+per expert with capacity or DeepSeek-V3's deduplicated dropless one, a TP
 ring collective pair (:mod:`repro.workloads.tp`) or a pipeline
 stage-boundary exchange (:mod:`repro.workloads.pipe`) — for one
 architecture from :mod:`repro.configs` at one rank count.
 :data:`DEFAULT_SCENARIOS` enumerates the shipped set over the production
-configs; :func:`default_machines` supplies the machine presets (two GPU
+configs, and :data:`SCENARIOS` every scenario by name;
+:func:`default_machines` supplies the machine presets (two GPU
 machines plus the paper's CPU baseline, all sized to the same 64 ranks);
 :func:`sweep` prices every scenario phase on every machine through **one**
 :func:`repro.comm.strategies.best_strategy_many` arena and returns rows
@@ -24,11 +26,11 @@ from repro.configs import get_config
 from repro.net.machine import (blue_waters_machine, frontier_machine,
                                lassen_machine)
 
-from .moe import moe_a2a_pattern
+from .moe import dedup_a2a_pattern, moe_a2a_pattern
 from .pipe import pipeline_p2p_pattern
 from .tp import tp_collective_patterns
 
-WORKLOADS = ("moe_a2a", "tp_collective", "pipeline_p2p")
+WORKLOADS = ("moe_a2a", "moe_a2a_dedup", "tp_collective", "pipeline_p2p")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,14 +65,18 @@ def scenario_patterns(sc: Scenario):
     """Derive ``sc``'s labelled, unbound phase list.
 
     Returns ``[(label, CommPattern), ...]`` in schedule order: MoE gives
-    the dispatch + combine exchanges, TP the reduce-scatter + all-gather
-    rings, pipeline a single p2p phase.  Deterministic per the workload
+    the dispatch + combine exchanges (``moe_a2a_dedup``:
+    :func:`~repro.workloads.moe.dedup_a2a_pattern`), TP the
+    reduce-scatter + all-gather rings, pipeline a single p2p phase.  Deterministic per the workload
     modules' RNG contracts.
     """
     cfg = get_config(sc.arch)
     if sc.workload == "moe_a2a":
         return moe_a2a_pattern(cfg, sc.n_ranks, sc.tokens_per_rank,
                                seed=sc.seed).phases()
+    if sc.workload == "moe_a2a_dedup":
+        return dedup_a2a_pattern(cfg, sc.n_ranks, sc.tokens_per_rank,
+                                 seed=sc.seed).phases()
     if sc.workload == "tp_collective":
         return tp_collective_patterns(cfg, sc.n_ranks,
                                       sc.tokens_per_rank).phases()
@@ -94,6 +100,16 @@ DEFAULT_SCENARIOS = (
              workload="pipeline_p2p", n_ranks=64, tokens_per_rank=512,
              n_stages=8, n_microbatches=8),
 )
+
+#: Every scenario by name: the shipped set and those left out of the
+#: default sweep (and so of its pinned winner table).  ``deepseek-v3-a2a``
+#: is DeepSeek-V3's node-limited, deduplicated, dropless exchange at one
+#: decode step of 32 tokens a rank over 64 ranks (each expert sees 64
+#: tokens a step).
+SCENARIOS = {sc.name: sc for sc in DEFAULT_SCENARIOS + (
+    Scenario(name="deepseek-v3-a2a", arch="deepseek-v3",
+             workload="moe_a2a_dedup", n_ranks=64, tokens_per_rank=32),
+)}
 
 
 def default_machines():

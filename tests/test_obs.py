@@ -23,6 +23,8 @@ from repro.serve import ArenaCache, StrategyService
 from repro.sparse import (RowPartition, build_hierarchy, elasticity_like_3d,
                           spmv_comm_pattern)
 from repro.sparse.partition import CommPattern
+from repro.workloads import (node_limited_topk, pattern_from_choices,
+                             pattern_from_counts)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -173,6 +175,47 @@ def test_rewrite_counts_its_masked_fan_out_passes(strategy, short, passes):
     obs.enable()
     rewrite(phase, strategy)
     assert obs.counters() == {"rewrite.fan_passes": passes}
+
+
+def _v3_choices(seed=0):
+    """256 tokens on each of 4 ranks routed node-limited, top-8 of 256
+    experts in 4 of 8 groups."""
+    scores = np.random.default_rng(seed).random((4 * 256, 256))
+    return node_limited_topk(scores, 8, 8, 4)
+
+
+def test_moe_lowering_counts_its_copies():
+    """A copy per chip: ``moe.token_copies`` are the (token, other rank)
+    pairs and no more than ``moe.expert_copies``, the off-rank (token,
+    expert) assignments; nothing is dropped.  Nothing is counted off."""
+    choices = _v3_choices()
+    pattern_from_choices(choices, 4, 256, 7392, 14336)
+    assert obs.counters() == {}
+    obs.enable()
+    pat = pattern_from_choices(choices, 4, 256, 7392, 14336)
+    c = obs.counters()
+    rank = np.repeat(np.arange(4), 256)[:, None]
+    owner = choices // 64
+    pairs = sum(len(set(row) - {r}) for row, r in
+                zip(owner.tolist(), rank[:, 0].tolist()))
+    assert c == {"moe.expert_copies": int((owner != rank).sum()),
+                 "moe.token_copies": pairs, "moe.dropped": 0}
+    assert c["moe.token_copies"] < c["moe.expert_copies"]
+    assert pat.dispatch.total_bytes == pairs * 7392
+
+
+def test_moe_capacity_lowering_counts_its_drops():
+    """A copy per expert: every off-rank assignment that survives the
+    capacity is a token copy, and the rest are ``moe.dropped``."""
+    counts = np.array([[5, 0, 3, 9], [1, 7, 2, 2]])
+    obs.enable()
+    pat = pattern_from_counts(counts, 8, capacity=4)
+    # rank 0 holds experts 0-1 and sends to 2-3, rank 1 the other way;
+    # clipped at 4: 5 -> 4, 9 -> 4, 7 -> 4
+    assert obs.counters() == {"moe.expert_copies": (3 + 9) + (1 + 7),
+                              "moe.token_copies": (3 + 4) + (1 + 4),
+                              "moe.dropped": 1 + 5 + 3}
+    assert pat.dropped_tokens == 9
 
 
 # -- on ----------------------------------------------------------------------
@@ -333,3 +376,20 @@ def test_segment_reduce_hands_its_layout_to_the_kernel_as_host_arrays(
             "device.h2d_bytes": vals.size * 4 + sum(a.nbytes
                                                     for a in layout)}
     assert obs.counters() == (want if on else {})
+
+
+@requires_jax
+def test_moe_route_and_lower_spans(tmp_path):
+    """Routing and lowering each open their span once, the routing with
+    its tokens and groups."""
+    scores = np.random.default_rng(1).random((64, 16))
+
+    def derive():
+        choices = node_limited_topk(scores, 4, 4, 2)
+        return pattern_from_choices(choices, 4, 16, 7392, 14336)
+
+    _, lines = _traced(tmp_path, derive)
+    (spans,) = lines.values()
+    assert [(n, st) for n, _, _, st in spans] == [
+        ("repro.workload.route", {"tokens": 64, "groups": 4}),
+        ("repro.workload.lower", {})]

@@ -12,6 +12,10 @@ Four layers of trust, weakest to strongest:
   (:func:`repro.workloads.router_routing_counts` — the numpy twin of the
   :mod:`repro.nn.moe` router math) equals the histogram lowering of its own
   counts, and obeys the same conservation law as the synthetic generator.
+* **Plain reference** (DeepSeek-V3's exchange): node-limited routing and
+  the one-copy-per-chip, dropless lowering equal the benchmark's per-token
+  reference (``bench/moe_dedup.py``, loaded by path) token by token and
+  pair for pair, at a small size and at the published widths.
 * **jax parity** (skipped where jax is absent): the numpy top-K routing
   reproduces ``jax.lax.top_k`` decisions on identical logits, and the
   numpy-only row-parallel op count matches the count read off the real
@@ -29,8 +33,10 @@ import pytest
 
 from _hypothesis_compat import given, settings, st
 from repro.configs import get_config, get_smoke_config
-from repro.workloads import (a2a_capacity, moe_a2a_pattern,
-                             pattern_from_counts, pipeline_p2p_pattern,
+from repro.workloads import (a2a_capacity, choice_counts, fp8_token_bytes,
+                             moe_a2a_pattern, node_limited_topk,
+                             pattern_from_choices, pattern_from_counts,
+                             pipeline_p2p_pattern, router_choices,
                              router_routing_counts, row_parallel_ops_per_layer,
                              synthetic_routing_counts, tp_collective_patterns)
 
@@ -275,3 +281,157 @@ def test_moe_full_size_conservation():
     assert _pair_bytes(pat.combine) == \
         {(d, s): z for (s, d), z in disp.items()}
     assert pat.capacity == a2a_capacity(256, cfg)
+
+
+# ------------------------------- DeepSeek-V3: node-limited, one copy a chip ----
+def _load_reference():
+    """``bench/moe_dedup.py``, the benchmark's plain reference, by path."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "moe_dedup.py")
+    spec = importlib.util.spec_from_file_location("_moe_dedup_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# (experts, groups, groups kept, top-k, ranks, tokens a rank)
+DEDUP_SIZES = {"small": (16, 4, 2, 4, 4, 32),
+               "published": (256, 8, 4, 8, 4, 256)}
+
+
+def _dedup(size, seed):
+    """Scores, the program's choices and pattern, and the reference's
+    choices and messages, at one of ``DEDUP_SIZES``."""
+    E, G, TG, K, M, T = DEDUP_SIZES[size]
+    ref = _load_reference()
+    scores = ref.scores(seed, M * T, E)
+    choices = node_limited_topk(scores, K, G, TG)
+    pat = pattern_from_choices(choices, M, E, fp8_token_bytes(7168),
+                               7168 * 2)
+    ref_choices = [ref.route(row, K, G, TG) for row in scores.tolist()]
+    ref_msgs = ref.exchange(ref_choices, M, E, 7392, 14336)
+    return scores, choices, pat, ref_choices, ref_msgs
+
+
+@pytest.mark.parametrize("seed", [0, 20260401])
+@pytest.mark.parametrize("size", sorted(DEDUP_SIZES))
+def test_dedup_matches_reference(size, seed):
+    """Routing token by token and lowering pair for pair, the program
+    equals the plain per-token reference of the benchmark."""
+    _, choices, pat, ref_choices, ref_msgs = _dedup(size, seed)
+    assert choices.tolist() == ref_choices
+    for got, (src, dst, size_) in zip((pat.dispatch, pat.combine), ref_msgs):
+        assert _pair_bytes(got) == {(int(s), int(d)): float(z)
+                                    for s, d, z in zip(src, dst, size_)}
+
+
+@pytest.mark.parametrize("size", sorted(DEDUP_SIZES))
+def test_node_limited_routing_stays_in_its_groups(size):
+    E, G, TG, K, M, T = DEDUP_SIZES[size]
+    scores, choices, *_ = _dedup(size, 3)
+    per = E // G
+    groups = choices // per
+    assert all(len(set(row)) <= TG for row in groups.tolist())
+    assert all(len(set(row)) == K for row in choices.tolist())
+    # the kept groups are those with the highest top-2 sums
+    top2 = np.sort(scores.reshape(-1, G, per), axis=2)[:, :, -2:].sum(2)
+    for t in range(0, M * T, 37):
+        kept = sorted(range(G), key=lambda g: (-top2[t, g], g))[:TG]
+        assert set(groups[t].tolist()) <= set(kept)
+
+
+@pytest.mark.parametrize("n_group", [1, 4, 8])
+def test_all_groups_kept_is_plain_topk(n_group):
+    """With ``topk_group == n_group`` the selection is today's stable
+    top-K, ties to the lower index included."""
+    rng = np.random.default_rng(n_group)
+    for scores in (rng.random((64, 32)),
+                   rng.integers(0, 4, (64, 32)).astype(np.float32)):
+        want = np.argsort(-scores, axis=1, kind="stable")[:, :6]
+        assert np.array_equal(
+            node_limited_topk(scores, 6, n_group, n_group), want)
+
+
+def test_node_limited_rejects_impossible_groups():
+    s = np.zeros((2, 16))
+    with pytest.raises(ValueError):
+        node_limited_topk(s, 4, 3, 2)             # 16 experts, 3 groups
+    with pytest.raises(ValueError):
+        node_limited_topk(s, 9, 4, 2)             # 9 > 2 groups x 4
+
+
+@pytest.mark.parametrize("size", sorted(DEDUP_SIZES))
+def test_dropless_sends_every_assignment(size):
+    E, G, TG, K, M, T = DEDUP_SIZES[size]
+    _, choices, pat, *_ = _dedup(size, 5)
+    counts = choice_counts(choices, M, E)
+    assert counts.sum() == M * T * K
+    assert pat.capacity is None and pat.dropped_tokens == 0
+    assert np.array_equal(pat.sent, counts)
+    per_expert = pattern_from_counts(counts, 7168, None)
+    assert per_expert.capacity is None and per_expert.dropped_tokens == 0
+    owner = np.repeat(np.arange(M), E // M)
+    off = sum(int(counts[r, e]) for r in range(M) for e in range(E)
+              if owner[e] != r)
+    assert per_expert.dispatch.total_bytes == off * per_expert.token_bytes
+
+
+@pytest.mark.parametrize("size", sorted(DEDUP_SIZES))
+def test_combine_is_dispatch_reversed_in_bf16(size):
+    *_, pat, _, _ = _dedup(size, 7)
+    disp = _pair_bytes(pat.dispatch)
+    assert _pair_bytes(pat.combine) == {
+        (d, s): z / 7392 * 14336 for (s, d), z in disp.items()}
+    assert pat.token_bytes == 7392
+    assert np.all(pat.dispatch.src != pat.dispatch.dst)
+
+
+@pytest.mark.parametrize("size", sorted(DEDUP_SIZES))
+def test_a_copy_per_chip_never_exceeds_a_copy_per_expert(size):
+    E, G, TG, K, M, T = DEDUP_SIZES[size]
+    _, choices, pat, *_ = _dedup(size, 9)
+    per_expert = pattern_from_counts(choice_counts(choices, M, E), 1, None,
+                                     act_bytes=1)
+    dedup = {k: z / 7392 for k, z in _pair_bytes(pat.dispatch).items()}
+    each = _pair_bytes(per_expert.dispatch)
+    assert set(dedup) == set(each)
+    assert all(dedup[k] <= each[k] for k in dedup)
+    assert sum(dedup.values()) < sum(each.values())
+
+
+def test_v3_config_and_scenario():
+    """DeepSeek-V3's published MoE fields, reached by name but kept out of
+    the LLM stack and of the default sweep."""
+    from repro.configs import ARCH_IDS
+    from repro.workloads import (DEFAULT_SCENARIOS, SCENARIOS,
+                                 scenario_patterns)
+    cfg = get_config("deepseek-v3")
+    assert (cfg.n_experts, cfg.n_experts_active, cfg.n_shared_experts,
+            cfg.moe_d_ff, cfg.d_model, cfg.first_dense_layers) == \
+        (256, 8, 1, 2048, 7168, 3)
+    assert (cfg.scoring_func, cfg.n_group, cfg.topk_group) == \
+        ("sigmoid", 8, 4)
+    assert "deepseek-v3" not in ARCH_IDS
+    sc = SCENARIOS["deepseek-v3-a2a"]
+    assert sc not in DEFAULT_SCENARIOS
+    assert all(SCENARIOS[d.name] is d for d in DEFAULT_SCENARIOS)
+    (_, disp), (_, comb) = scenario_patterns(sc)
+    assert disp.n_procs == 64 and disp.n_msgs > 0
+    assert comb.total_bytes == disp.total_bytes / 7392 * 14336
+    assert fp8_token_bytes(7168) == 7168 + 56 * 4
+
+
+def test_sigmoid_router_pass_is_node_limited():
+    import dataclasses
+    cfg = dataclasses.replace(get_config("deepseek-v3"), d_model=64,
+                              n_experts=16, n_experts_active=4, n_group=4,
+                              topk_group=2)
+    choices = router_choices(cfg, 4, 16, seed=2)
+    assert choices.shape == (64, 4)
+    assert all(len(set(row)) <= 2 for row in (choices // 4).tolist())
+    assert np.array_equal(router_routing_counts(cfg, 4, 16, seed=2),
+                          choice_counts(choices, 4, 16))
+    ungrouped = dataclasses.replace(cfg, n_group=1, topk_group=1)
+    assert not np.array_equal(router_choices(ungrouped, 4, 16, seed=2),
+                              choices)
