@@ -45,7 +45,11 @@ one per injector rank that only some messages reach: 0 where every node
 is full), and per MoE lowering ``moe.expert_copies`` (off-rank (token,
 expert) assignments), ``moe.token_copies`` (token copies the dispatch
 carries: fewer where a token goes once per rank) and ``moe.dropped``
-(assignments lost to capacity).
+(assignments lost to capacity), and per lowered exchange program
+(:func:`repro.exec.executor_program`) its round tables by lowering:
+``exec.block_tables`` (window copies of runs of consecutive units),
+``exec.gather_tables`` (per-word gather or scatter-add) and
+``exec.dropped_tables`` (no unit on any rank).
 
 Off (the default), :func:`span` returns one shared null context and
 :func:`count` returns at once: one global check per site, and no import

@@ -238,3 +238,157 @@ def test_device_digest_matches_payload_totals(mesh_results):
 def test_timed_run_reports_positive_median(mesh_results):
     assert mesh_results["median_s"] > 0.0
     assert mesh_results["n_rounds"] > 0
+
+
+# ------------------------------------ jax: run-aligned (block) lowering ----
+
+def _block_cases():
+    """Phases whose rounds take the window lowering of
+    :mod:`repro.exec.lower`, by name: ``(machine, strategy, coloring, src,
+    dst, size)``."""
+    from repro.exec.lower import MAX_RUNS
+    rng = np.random.default_rng(11)
+    src = rng.integers(0, 8, 40)
+    dst = (src + rng.integers(1, 8, 40)) % 8
+    size = rng.integers(1, 6000, 40).astype(float).tolist()
+    # one rank sends alternately to a node leader and to its neighbour:
+    # the inter-node message is one run, its arrivals alternate
+    k = 2 * MAX_RUNS + 2
+    relay_dst = np.where(np.arange(k) % 2 == 0, 4, 5).tolist()
+    return {
+        # rank 2's 3 units are the row's last, in a round 10 wide
+        "clamp": ("lassen_8", "standard", "greedy", [0, 2], [1, 3],
+                  [10 * 512.0, 3 * 512.0]),
+        "per_message": ("frontier_8", "standard", "per_message",
+                        src.tolist(), dst.tolist(), size),
+        "per_message_two_step": ("blue_waters_8", "two_step", "per_message",
+                                 src.tolist(), dst.tolist(), size),
+        "relay": ("blue_waters_8", "two_step", "greedy", [1] * k, relay_dst,
+                  [512.0] * k),
+    }
+
+
+BLOCK_CASES = _block_cases()
+
+
+def _block_schedule(name):
+    mname, strat, coloring, src, dst, size = BLOCK_CASES[name]
+    ph = CommPhase.build(MACHINES[mname], src, dst, size, n_procs=8)
+    return build_schedule(ph, strat, coloring=coloring)
+
+
+def _lowered(sched):
+    """Every round's ``(pack, stage, final)`` as :mod:`repro.exec.lower`
+    lowers them: ``(rots, arg)`` each."""
+    from repro.exec.lower import _lower_table
+    cols = sched.n_units + 1
+    return [tuple(_lower_table(t, cols, gather)
+                  for t, gather in ((r.pack, True), (r.stage, False),
+                                    (r.final, False)))
+            for ph in sched.phases for r in ph.rounds]
+
+
+def test_block_case_clamp_shifts_the_window():
+    """The last message's window would overrun the row: its start moves
+    inside and the data rotates, on the sender and the receiver."""
+    from repro.exec.lower import _ROT, _START
+    (pack, stage, final), = _lowered(_block_schedule("clamp"))
+    assert stage == ((), None)
+    for rots, arg in (pack, final):
+        assert rots == (True,)
+        assert arg[:, 0, _START].max() == 13 + 1 - 10
+    assert pack[1][2, 0, _ROT] == 6 and final[1][3, 0, _ROT] == 4
+
+
+def test_block_case_per_message_leaves_most_ranks_idle():
+    from repro.exec.lower import _HI
+    for name in ("per_message", "per_message_two_step"):
+        for pack, _, final in _lowered(_block_schedule(name)):
+            assert pack[0] is not None and len(pack[0]) >= 1
+            # one message a round: every other rank's run is empty
+            assert (pack[1][:, :, _HI] > 0).any(axis=1).sum() == 1
+
+
+def test_block_case_relay_windows_the_pack_not_the_stage():
+    rounds = _lowered(_block_schedule("relay"))
+    assert any(pack[0] is not None and stage[0] is None
+               for pack, stage, _ in rounds)
+
+
+def test_lowering_counters_follow_the_tables():
+    """``standard`` rounds lower as windows with the all-sink ``stage``
+    dropped; ``three_step``'s striped shares stay per word; nothing is
+    counted with tracing off."""
+    import jax
+    from repro.comm import obs
+    from repro.exec import executor_program
+    mesh = jax.sharding.AbstractMesh((8,), ("rank",))
+    m = MACHINES["blue_waters_8"]
+    standard = build_schedule(_phase(m), "standard")
+    # 64-unit messages over 4 injectors: rows of 16 one-unit runs and more
+    striped = build_schedule(
+        CommPhase.build(m, [1, 2], [6, 5], [64 * 512.0, 96 * 512.0],
+                        n_procs=8), "three_step")
+    names = ("exec.block_tables", "exec.gather_tables",
+             "exec.dropped_tables")
+    obs.disable()
+    obs.reset()
+    try:
+        executor_program(standard, mesh)
+        executor_program(striped, mesh)
+        assert [obs.counters().get(n, 0) for n in names] == [0, 0, 0]
+        obs.enable()
+        executor_program(standard, mesh)
+        c = obs.counters()
+        assert c["exec.block_tables"] == 2 * standard.n_rounds
+        assert c["exec.dropped_tables"] == standard.n_rounds
+        assert c["exec.gather_tables"] == 0
+        obs.reset()
+        executor_program(striped, mesh)
+        assert obs.counters()["exec.gather_tables"] > 0
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+BLOCK_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import json
+from repro.comm import obs
+from repro.comm.phase import CommPhase
+from repro.exec import build_schedule, execute, host_machines, run_reference
+
+obs.enable()
+out = {}
+for name, (mname, strat, coloring, src, dst, size) in json.loads(
+        sys.argv[1]).items():
+    obs.reset()
+    ph = CommPhase.build(host_machines()[mname], src, dst, size, n_procs=8)
+    sched = build_schedule(ph, strat, coloring=coloring)
+    got, _ = execute(sched)
+    out[name] = {"mismatches": int((got != run_reference(sched)).sum()),
+                 "counters": obs.counters()}
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def block_results():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")    # never the parent's chip
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", BLOCK_SCRIPT,
+                          json.dumps(BLOCK_CASES)], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("name", sorted(BLOCK_CASES))
+def test_block_path_bit_identical_on_8_device_mesh(block_results, name):
+    r = block_results[name]
+    assert r["mismatches"] == 0
+    assert r["counters"]["exec.block_tables"] > 0
+    if name == "relay":
+        assert r["counters"]["exec.gather_tables"] > 0

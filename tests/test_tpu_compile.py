@@ -109,9 +109,8 @@ def _four_rank_phase():
     return CommPhase.build(m, src, dst, size, n_procs=4)
 
 
-@pytest.mark.parametrize("strategy", ["standard", "two_step", "three_step"])
-def test_exec_program_compiles_on_v5e_mesh(topo, strategy,
-                                           no_persistent_cache):
+def _exchange_text(topo, strategy):
+    """The compiled text of the 4-rank exchange under ``strategy``."""
     from repro.exec import build_schedule, executor_program
 
     mesh = Mesh(np.asarray(topo.devices), ("rank",))
@@ -119,5 +118,19 @@ def test_exec_program_compiles_on_v5e_mesh(topo, strategy,
     fn, args = executor_program(sched, mesh)
     rank = NamedSharding(mesh, PartitionSpec("rank"))
     shapes = jax.tree.map(lambda a: _shape(a.shape, a.dtype, rank), args)
-    compiled = fn.lower(*shapes).compile()
-    assert "collective-permute" in compiled.as_text()
+    return fn.lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("strategy", ["standard", "two_step", "three_step"])
+def test_exec_program_compiles_on_v5e_mesh(topo, strategy,
+                                           no_persistent_cache):
+    assert "collective-permute" in _exchange_text(topo, strategy)
+
+
+def test_standard_exchange_runs_block_copies_on_v5e(topo,
+                                                    no_persistent_cache):
+    """``standard`` rounds carry runs of consecutive units: the chip moves
+    them as window copies, with no per-word scatter left."""
+    text = _exchange_text(topo, "standard")
+    assert "scatter" not in text
+    assert "collective-permute" in text
