@@ -68,7 +68,7 @@ def bench_fault_sites():
 
     us_off, n = _best_of(_probe_once, reps=2)
     rows = [("faults_site_disarmed", us_off / n, 1.0)]
-    with faults.inject("autotune.cache_write", "raise"):   # never matches
+    with faults.inject("serve.cache_write", "raise"):      # never matches
         us_miss, n = _best_of(_probe_once, reps=2)
     rows.append(("faults_site_armed_miss", us_miss / n, us_off / us_miss))
     return rows

@@ -17,22 +17,6 @@ regression still trips it:
   rebuild per query, cached verdicts asserted bit-identical inside the
   bench) — the fingerprint cache must never lose to re-running the sweep
   (>= 1.0x; in practice the hit path is orders of magnitude ahead);
-* the ``stack_auto_*`` rows of :mod:`benchmarks.bench_stack_backends` —
-  the autotuned backend default must never pick a backend slower than
-  numpy.  On a host whose crossover probe reports ``inf`` (CPU-only jax,
-  or no jax) auto *is* the numpy path, so the ratio is pure dispatch
-  overhead plus timing noise on an identical code path; the thresholds
-  are documented noise floors rather than 1.0x for exactly that reason —
-  at 1.0 the gate would coin-flip on same-path jitter, while a backend
-  mispick shows up far below them.  The dispatch overhead is O(1)
-  (one memoized resolution), so the large-arena row sits at ~1.0x and
-  gates at 0.9x; the small-arena row divides the same microseconds by a
-  ~80us baseline and gates at 0.85x;
-* the ``stack_jax_vs_onehot`` row of the same bench — the fused jitted
-  segment reduction must beat the retired one-hot matmul kernel it
-  replaced (>= 1.0x; in practice it is orders of magnitude ahead).  The
-  row only exists where jax is importable; a CSV without it is accepted
-  when produced on a jax-less host;
 * the ``llm_sweep_stacked`` row of :mod:`benchmarks.bench_llm_workloads`
   (the registry's ONE cross-machine ``best_strategy_many`` arena vs the
   per-pattern ``best_strategy`` loop on the same bound phases, verdicts
@@ -49,9 +33,8 @@ regression still trips it:
   one-``ppermute``-per-message lowering of the same exchange on the
   forced 8-device host mesh, delivered payloads asserted identical inside
   the bench — fusing messages into permutation rounds must never lose to
-  the per-message loop (>= 1.0x).  Like ``stack_jax_vs_onehot`` the row
-  only exists where jax is importable, so it is optional in a CSV from a
-  jax-less host.
+  the per-message loop (>= 1.0x).  The row only exists where jax is
+  importable, so it is optional in a CSV from a jax-less host.
 
 Usage::
 
@@ -67,10 +50,6 @@ import sys
 
 STACK_ROWS = ("stack_model_ladder", "stack_simulate", "stack_best_strategy")
 DELTA_ROWS = ("delta_local_search_64", "delta_service_qps")
-#: autotuned-default rows: same-code-path comparison -> noise-floor gate
-AUTO_ROWS = ("stack_auto_small", "stack_auto_large")
-#: fused-kernel-vs-retired-one-hot row: present only where jax imports
-JAX_ROWS = ("stack_jax_vs_onehot",)
 #: registry cross-machine arena vs per-scenario loop (numpy-only)
 LLM_ROWS = ("llm_sweep_stacked",)
 #: calibrated-model crossover agreement (numpy-only, always present)
@@ -78,20 +57,15 @@ EXEC_ROWS = ("exec_agreement_crossover",)
 #: colored-vs-naive lowered schedule: present only where jax imports
 EXEC_JAX_ROWS = ("exec_standard_vs_naive",)
 
-GATED_ROWS = (STACK_ROWS + DELTA_ROWS + AUTO_ROWS + JAX_ROWS + LLM_ROWS
-              + EXEC_ROWS + EXEC_JAX_ROWS)
-OPTIONAL_ROWS = frozenset(JAX_ROWS + EXEC_JAX_ROWS)
+GATED_ROWS = STACK_ROWS + DELTA_ROWS + LLM_ROWS + EXEC_ROWS + EXEC_JAX_ROWS
+OPTIONAL_ROWS = frozenset(EXEC_JAX_ROWS)
 
 #: per-row minimum ``derived`` speedup (see the module docstring)
 THRESHOLD = {name: 1.0 for name in GATED_ROWS}
-THRESHOLD["stack_auto_small"] = 0.85      # O(1) dispatch / tiny baseline
-THRESHOLD["stack_auto_large"] = 0.9
 
 #: reference path and unit per row family, for the report line
 _REF = {**{n: ("loop", "us/sweep") for n in STACK_ROWS},
         **{n: ("rebuild", "us/search") for n in DELTA_ROWS},
-        **{n: ("numpy", "us/eval") for n in AUTO_ROWS},
-        **{n: ("one-hot", "us/reduce") for n in JAX_ROWS},
         **{n: ("loop", "us/sweep") for n in LLM_ROWS},
         **{n: ("simulator", "us/sweep") for n in EXEC_ROWS},
         **{n: ("naive", "us/run") for n in EXEC_JAX_ROWS}}
@@ -120,10 +94,8 @@ def main() -> None:
         from .bench_exec import bench_exec_agreement, bench_exec_schedules
         from .bench_kernels import bench_phase_stack
         from .bench_llm_workloads import bench_llm_workloads
-        from .bench_stack_backends import bench_stack_backends
         rows = (bench_phase_stack() + bench_delta_local_search()
                 + bench_service_qps()
-                + [r for r in bench_stack_backends() if r[0] in GATED_ROWS]
                 + [r for r in bench_llm_workloads() if r[0] in GATED_ROWS]
                 + [r for r in bench_exec_agreement() if r[0] in GATED_ROWS]
                 + [r for r in bench_exec_schedules() if r[0] in GATED_ROWS])

@@ -11,13 +11,11 @@ def main() -> None:
 
     use_compile_cache()
     from . import (bench_paper, bench_kernels, bench_roofline, bench_delta,
-                   bench_stack_backends, bench_llm_workloads, bench_faults,
-                   bench_exec)
+                   bench_llm_workloads, bench_faults, bench_exec)
     print("name,us_per_call,derived")
     failures = 0
     for mod in (bench_paper, bench_kernels, bench_roofline, bench_delta,
-                bench_stack_backends, bench_llm_workloads, bench_faults,
-                bench_exec):
+                bench_llm_workloads, bench_faults, bench_exec):
         for bench in mod.ALL_BENCHES:
             try:
                 for (name, us, derived) in bench():

@@ -704,12 +704,7 @@ class DeltaStack:
         delegates to a fresh arena over the current phases (built once per
         generation), so results stay correct either way.
         """
-        backend_name, mod = PhaseStack._backend(backend)  # eager validation
-        if backend_name == "auto":
-            # resolve the autotuned default here so auto -> numpy keeps the
-            # O(changed) delta fast path (auto -> jax delegates, correctly)
-            backend_name = mod.resolve_backend("auto",
-                                               n_values=self.total_msgs)
+        backend_name, _ = PhaseStack._backend(backend)  # eager validation
         N = self.n_phases
         zeros = np.zeros(N)
         if N == 0 or self.total_msgs == 0:
@@ -745,10 +740,7 @@ class DeltaStack:
         maintained receive counts, custom orders pay the per-phase Fenwick
         walk.
         """
-        backend_name, mod = PhaseStack._backend(backend)
-        if backend_name == "auto":
-            backend_name = mod.resolve_backend("auto",
-                                               n_values=self.total_msgs)
+        backend_name, _ = PhaseStack._backend(backend)
         if backend_name != "numpy":
             return self._fresh().sim_arrays(recv_post_orders, arrival_orders,
                                             backend=backend_name)

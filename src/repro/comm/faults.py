@@ -3,10 +3,11 @@
 Every device-backend call in the pricing stack passes through a **named
 injection site**: the fused segment reduction and queue walk in
 :mod:`repro.kernels.comm_stack`, the device column shipping in
-:meth:`repro.comm.PhaseStack._dev`, and the autotune live probe and disk
-cache.  This module arms those sites: an armed site can *raise*, *time out*,
-*NaN-poison* its output, or *corrupt* it — deterministically (no randomness,
-an optional fire-count), so a CI chaos run reproduces exactly.
+:meth:`repro.comm.PhaseStack._dev`, and the strategy service's arena cache
+and deadline.  This module arms those sites: an armed site can *raise*,
+*time out*, *NaN-poison* its output, or *corrupt* it — deterministically
+(no randomness, an optional fire-count), so a CI chaos run reproduces
+exactly.
 
 Sites (:data:`SITES`):
 
@@ -14,17 +15,14 @@ Sites (:data:`SITES`):
 ``kernel.segment_reduce``   jitted/Pallas segment sum/max reductions
 ``kernel.queue_walk``       the device Fenwick queue sweep
 ``stack.device_store``      arena column shipping to the device
-``autotune.probe``          the live numpy/jax crossover probe
-``autotune.cache_read``     autotune disk-cache read
-``autotune.cache_write``    autotune disk-cache write
 ``serve.cache_read``        strategy-service arena-cache read
 ``serve.cache_write``       strategy-service arena-cache write
 ``serve.deadline``          strategy-service per-request deadline check
 ==========================  =================================================
 
 Modes (:data:`MODES`): ``raise`` (an :class:`InjectedFault`), ``timeout``
-(an :class:`InjectedTimeout`, an ``OSError``/``TimeoutError`` so cache and
-probe paths see a realistic failure type), ``nan`` (float outputs filled
+(an :class:`InjectedTimeout`, an ``OSError``/``TimeoutError`` so cache
+paths see a realistic failure type), ``nan`` (float outputs filled
 with NaN — pair with ``REPRO_STACK_VERIFY=finite`` to detect it), and
 ``corrupt`` (numeric outputs shifted off their true values, strings/bytes
 garbled — pair with ``REPRO_STACK_VERIFY=parity``).
@@ -38,7 +36,7 @@ Arming a site, two equivalent ways:
 
 * the ``REPRO_FAULT_INJECT`` env var (CI chaos runs): a comma-separated
   list of ``site:mode`` or ``site:mode:times`` entries, where ``site`` may
-  be a glob (``kernel.*:raise,autotune.probe:timeout:1``).
+  be a glob (``kernel.*:raise,serve.deadline:timeout:1``).
 
 Instrumented code calls :func:`fail_point` (raises for armed raise/timeout
 specs) and :func:`poison` (transforms outputs for armed nan/corrupt specs);
@@ -66,9 +64,6 @@ SITES = (
     "kernel.segment_reduce",
     "kernel.queue_walk",
     "stack.device_store",
-    "autotune.probe",
-    "autotune.cache_read",
-    "autotune.cache_write",
     "serve.cache_read",
     "serve.cache_write",
     "serve.deadline",
@@ -90,8 +85,8 @@ class InjectedFault(RuntimeError):
 class InjectedTimeout(InjectedFault, TimeoutError):
     """An injected timeout (mode ``timeout``).
 
-    Also a ``TimeoutError`` (hence ``OSError``), so the disk-cache and
-    probe paths — which guard against real I/O failures — see the same
+    Also a ``TimeoutError`` (hence ``OSError``), so the disk-cache
+    paths — which guard against real I/O failures — see the same
     exception family a genuine timeout would produce.
     """
 

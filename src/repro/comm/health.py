@@ -19,8 +19,9 @@ module is the per-process ledger of those events:
   quarantine).
 * The same object owns the process's **resettable warn-once registry**
   (:meth:`BackendHealth.warn_once`): every "warn once per process" message
-  in the stack (backend fallbacks, the deprecated one-hot shim) goes through
-  it, so tests can reset warning state instead of poking module globals.
+  in the stack (backend fallbacks, first degradations, breaker openings)
+  goes through it, so tests can reset warning state instead of poking
+  module globals.
 * :class:`CircuitBreaker` is the *service-path* failure policy
   (:class:`repro.serve.StrategyService`), replacing the stack's one-shot
   quarantine counter one level up: repeated failures **open** the breaker

@@ -28,17 +28,15 @@ the identical contiguous slice of the stacked mask (:meth:`masked_phase_sums`,
 O(n_phases) trivial slice-sums; all per-message work stays in the single
 pass).
 
-Device backends (``backend='jax' | 'pallas' | 'auto'``, or the
-``REPRO_STACK_BACKEND`` env var) route the packed-key transport/contention
-reductions and the Fenwick queue sweep through
+Device backends (``backend='jax' | 'pallas'``) route the packed-key
+transport/contention reductions and the Fenwick queue sweep through
 :mod:`repro.kernels.comm_stack`, with the hot per-message columns cached
 device-resident on first use (one transfer per arena, not per call) and the
 message pricing itself run under the backend's array namespace
-(:mod:`repro.comm.xp`).  ``'auto'`` is the autotuned default: it collapses
-per call to numpy below the measured numpy/jax crossover size and to jax
-at/above it.  numpy remains the default and the fallback; float backend
-results are allclose (not bit-equal, the device path runs float32) while
-queue steps are integer work and bit-equal everywhere.
+(:mod:`repro.comm.xp`).  numpy (``backend=None``) is the default and the
+fallback; float backend results are allclose (not bit-equal, the device
+path runs float32) while queue steps are integer work and bit-equal
+everywhere.
 
 Arenas can also be built *streaming* (:meth:`PhaseStack.build_streaming`):
 phases from any iterable are appended through fixed-size buffers and the
@@ -54,7 +52,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any
 
 import numpy as np
@@ -69,12 +66,10 @@ from .primitives import active_senders_per_node
 
 __all__ = ["PhaseStack", "StackSimArrays", "as_stack", "STACK_BACKENDS"]
 
-#: Allowed values for the ``backend`` kwarg and the ``REPRO_STACK_BACKEND``
-#: env var.  Mirrors ``repro.kernels.comm_stack.BACKENDS`` — duplicated here
-#: so eager validation never has to import the (jax-adjacent) kernels module.
-#: ``'auto'`` is the autotuned default: numpy below the measured numpy/jax
-#: crossover size, jax at/above it, resolved per call.
-STACK_BACKENDS = ("numpy", "jax", "pallas", "auto")
+#: Allowed values for the ``backend`` kwarg (``None`` means numpy).  Mirrors
+#: ``repro.kernels.comm_stack.BACKENDS`` — duplicated here so eager
+#: validation never has to import the (jax-adjacent) kernels module.
+STACK_BACKENDS = ("numpy", "jax", "pallas")
 
 
 def as_stack(phases) -> "PhaseStack | None":
@@ -354,8 +349,8 @@ class PhaseStack:
     def _machine_transport(self) -> np.ndarray:
         """Dense per-(phase, process) sums of :attr:`_machine_t_msg`.
 
-        Pinned to the numpy backend (not ``None``): the cache must stay
-        bit-exact even when ``REPRO_STACK_BACKEND`` selects an accelerator.
+        Pinned to the numpy backend: the cache stays bit-exact whatever
+        backend a caller prices with.
         """
         return self._phase_proc_sums(self._machine_t_msg, self._src_key,
                                      backend="numpy")
@@ -374,48 +369,22 @@ class PhaseStack:
     def _backend(backend):
         """Resolve a backend name to ('numpy', None) or (name, kernels mod).
 
-        Validation is eager and happens *here*, before any reduction runs:
-        an unknown name — whether passed as the ``backend`` kwarg or set in
-        the ``REPRO_STACK_BACKEND`` env var — raises a ``ValueError`` naming
-        the allowed values and where the bad name came from, instead of
-        failing deep inside a segmented pass.
+        ``None`` means numpy.  Validation is eager and happens *here*,
+        before any reduction runs: an unknown name raises a ``ValueError``
+        naming the allowed values, instead of failing deep inside a
+        segmented pass.  A device backend that cannot run (no jax, or
+        quarantined) resolves to numpy
+        (:func:`repro.kernels.comm_stack.resolve_backend`).
         """
-        source = "the backend argument"
-        if backend is None:
-            backend = os.environ.get("REPRO_STACK_BACKEND", "numpy")
-            source = "the REPRO_STACK_BACKEND environment variable"
+        if backend is None or backend == "numpy":
+            return "numpy", None
         if backend not in STACK_BACKENDS:
             raise ValueError(
-                f"unknown stack backend {backend!r} (from {source}); "
-                f"allowed values: {STACK_BACKENDS}")
-        if backend == "numpy":
-            return "numpy", None
+                f"unknown stack backend {backend!r} (from the backend "
+                f"argument); allowed values: {STACK_BACKENDS}")
         from repro.kernels import comm_stack   # lazy: keeps comm numpy-only
         backend = comm_stack.resolve_backend(backend)
         return backend, (None if backend == "numpy" else comm_stack)
-
-    def _resolved_backend(self, backend):
-        """Like :meth:`_backend`, with ``'auto'`` collapsed for this arena.
-
-        The autotuned default resolves against the arena's message count:
-        numpy below the measured numpy/jax crossover size (the exact numpy
-        paths and caches, bit-identical), jax at/above it
-        (:func:`repro.kernels.comm_stack.autotune_crossover`).  The choice
-        is memoized per arena — ``total_msgs`` is immutable and the
-        crossover is a process-wide constant, so re-resolving on every
-        reduction pass would only add dispatch overhead to the small-arena
-        path the autotuner exists to protect.
-        """
-        name, mod = self._backend(backend)
-        if name == "auto":
-            cached = self.__dict__.get("_auto_choice")
-            if cached is None:
-                cached = mod.resolve_backend("auto", n_values=self.total_msgs)
-                self.__dict__["_auto_choice"] = cached
-            name = cached
-            if name == "numpy":
-                mod = None
-        return name, mod
 
     # -- device-resident columns --------------------------------------------
     @functools.cached_property
@@ -457,7 +426,7 @@ class PhaseStack:
         """Dense [n_phases, proc_span] sums of ``values`` by a packed
         (phase, process) key (``_src_key`` / ``_dst_key``)."""
         n = self.n_phases * self.proc_span
-        backend, mod = self._resolved_backend(backend)
+        backend, mod = self._backend(backend)
         if mod is None:
             dense = np.bincount(key, weights=values, minlength=n)
         else:
@@ -490,8 +459,7 @@ class PhaseStack:
         ``use_maxrate`` select the ladder rung's transport formula;
         ``backend`` routes the pricing and segmented reductions through
         :mod:`repro.kernels.comm_stack` (``'jax'``/``'pallas'`` run
-        device-resident off the cached column store; ``'auto'`` picks
-        numpy or jax per call at the autotuned crossover size).
+        device-resident off the cached column store; ``None`` is numpy).
         :func:`repro.core.models.phase_cost_many` prices them into
         ``CostBreakdown`` rows bit-identical to the per-phase loop.
         """
@@ -502,7 +470,7 @@ class PhaseStack:
         m = self.machine
         p = params if params is not None else m.params
         same_net = p.network_locality == m.params.network_locality
-        backend_name, mod = self._resolved_backend(backend)
+        backend_name, mod = self._backend(backend)
         flags = (node_aware, use_maxrate)
         cacheable = p is m.params and backend_name == "numpy"
         if cacheable and flags in self._ladder_cache:
@@ -717,7 +685,7 @@ class PhaseStack:
         one fused program and, being integer work, is *bit-equal* to numpy.
         """
         P = self.proc_span
-        backend_name, _ = self._resolved_backend(backend)
+        backend_name, _ = self._backend(backend)
         qsteps = grouped_queue_steps(
             self._dst_key, self.n_phases * P,
             recv_post_order=self._flatten_orders(recv_post_orders),
@@ -759,7 +727,7 @@ class PhaseStack:
         """Cached numpy-backend :meth:`link_contention_many` — the routing
         expansion depends only on the arena, never on receive orders, so
         repeated simulations of a bound stack reuse it.  Pinned to numpy so
-        ``REPRO_STACK_BACKEND`` cannot poison the bit-exact cache."""
+        a device backend cannot poison the bit-exact cache."""
         return self._compute_link_contention("numpy")
 
     def link_contention_many(self, backend=None):
@@ -774,7 +742,7 @@ class PhaseStack:
         :meth:`CommPhase.link_contention` — and bit-identically so: within a
         phase the packed keys sort and accumulate in the per-phase order.
         """
-        backend_name, _ = self._resolved_backend(backend)
+        backend_name, _ = self._backend(backend)
         if backend_name == "numpy":
             return self._link_contention
         return self._compute_link_contention(backend_name)
@@ -804,7 +772,7 @@ class PhaseStack:
             per_src = np.bincount(inv, weights=w)  # bytes/(phase, link, src)
             pair = uk // src_span                  # (phase, link) runs
             starts = np.nonzero(np.r_[True, pair[1:] != pair[:-1]])[0]
-        backend, mod = self._resolved_backend(backend)
+        backend, mod = self._backend(backend)
         if mod is None:
             totals = np.add.reduceat(per_src, starts)
             largest = np.maximum.reduceat(per_src, starts)
@@ -841,7 +809,7 @@ class PhaseStack:
         if self.n_phases == 0:
             z = np.zeros(0)
             return StackSimArrays(z, [], [], z.copy(), z.copy())
-        backend_name, mod = self._resolved_backend(backend)
+        backend_name, mod = self._backend(backend)
         if backend_name == "numpy":
             dense = self._machine_transport    # cached, shared with the model
         else:

@@ -80,7 +80,7 @@ import numpy as np
 from . import obs
 from .phase import CommPhase
 from .primitives import segmented_arange, sum_by_pairs
-from .stack import as_stack
+from .stack import PhaseStack, as_stack
 
 STRATEGIES = ("standard", "two_step", "three_step")
 
@@ -545,9 +545,10 @@ def best_strategy_many(patterns, machine=None, *, strategies=None,
     layer over every pattern before anything is rewritten
     (:func:`repro.comm.guard.validate_messages` — precise
     :class:`~repro.comm.guard.PatternError` subclasses).  ``backend``
-    routes the stacked passes through a device backend; every device site
-    already degrades to numpy on failure, and should the pricing passes
-    still raise on a non-numpy backend, the sweep is retried once on
+    (``None`` is numpy; an unknown name raises ``ValueError`` before any
+    work) routes the stacked passes through a device backend; every device
+    site already degrades to numpy on failure, and should the pricing
+    passes still raise on a device backend, the sweep is retried once on
     ``backend='numpy'``.  Verdicts priced under any fallback carry
     ``degraded=True`` with the events recorded in
     :func:`repro.comm.health.get_health`.
@@ -555,6 +556,7 @@ def best_strategy_many(patterns, machine=None, *, strategies=None,
     if arrival not in ("random", "posted"):
         raise ValueError(f"unknown arrival regime {arrival!r}; "
                          "expected 'random' or 'posted'")
+    PhaseStack._backend(backend)            # eager: a bad name never retries
     patterns = list(patterns)
     stats = (_sweep_stats(patterns, machine, strategies) if obs.enabled()
              else {})
@@ -665,11 +667,10 @@ def _sweep(patterns, machine, strategies, level, arrival, seed, params,
     try:
         costs, sims = _price(backend)
     except Exception as e:  # noqa: BLE001 - serve-layer degradation
-        if backend == "numpy":
+        if backend in (None, "numpy"):
             raise       # the reference path itself failed: a real error
-        # backend=None may still resolve to a device backend through the
-        # REPRO_STACK_BACKEND env default, so the numpy retry applies to it
-        # too; a genuine input error re-raises from the retry unchanged
+        # a device backend failed outside its own guards: price on numpy; a
+        # genuine input error re-raises from the retry unchanged
         health.record_failure(str(backend), "strategies.best_strategy_many",
                               e)
         costs, sims = _price("numpy")

@@ -24,8 +24,7 @@ def get_xp(backend: str | None):
 
     ``None`` / ``"numpy"`` -> :mod:`numpy`; ``"jax"`` / ``"pallas"`` ->
     :mod:`jax.numpy` (imported lazily — tier-1 environments without jax
-    never pay the import).  ``"auto"`` is not accepted here: resolve it
-    first (:func:`repro.kernels.comm_stack.resolve_backend`).
+    never pay the import).
     """
     if backend is None or backend == "numpy":
         return np

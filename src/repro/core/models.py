@@ -269,9 +269,8 @@ def phase_cost_many(phases, level: str = "contention",
     per-phase loop, which remains the fallback for single phases and
     mixed-machine sweeps.  A ``DeltaStack`` is priced from its incremental
     caches (even for a single phase, which is the partition-optimizer case).
-    ``backend`` selects the arena's reduction backend: numpy (default, or
-    via ``REPRO_STACK_BACKEND``), ``'jax'``/``'pallas'`` device-resident, or
-    ``'auto'`` — the autotuned per-call numpy/jax choice.
+    ``backend`` selects the arena's reduction backend: numpy (``None``, the
+    default) or ``'jax'``/``'pallas'`` device-resident.
     """
     if level not in MODEL_LEVELS:
         raise ValueError(f"unknown model level {level!r}")

@@ -236,7 +236,8 @@ def execute(schedule: ExecSchedule, mesh=None,
     digest)``: the delivered int32 matrix and its per-rank payload totals
     reduced through the fused segment kernels
     (:func:`repro.exec.reference.delivered_digest`, device-backed when
-    ``digest_backend`` is ``'jax'``/``'pallas'``).  ``mesh`` as in
+    ``digest_backend`` is ``'jax'``/``'pallas'``, numpy when it is
+    ``None``).  ``mesh`` as in
     :func:`build_executor`."""
     from .reference import delivered_digest
     delivered = build_executor(schedule, mesh=mesh)()

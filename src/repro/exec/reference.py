@@ -72,7 +72,7 @@ def delivered_digest(delivered: np.ndarray, schedule: ExecSchedule,
     through the fused segment kernels
     (:func:`repro.kernels.comm_stack.segment_sum`) — the on-device
     aggregation path when ``backend`` is ``'jax'``/``'pallas'``, the numpy
-    reference otherwise.  For a correct execution of ``schedule`` this
+    reference when it is ``None`` or ``'numpy'``.  For a correct execution of ``schedule`` this
     equals ``segment_sum(payload, unit_dst, n_procs)``."""
     from repro.kernels.comm_stack import segment_sum
     units = np.arange(schedule.n_units)

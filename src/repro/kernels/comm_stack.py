@@ -3,9 +3,13 @@
 The stacked sweep engine (:mod:`repro.comm.stack`) reduces per-message
 quantities to per-(phase, process) / per-(phase, link) aggregates with
 segmented sums/maxima over packed integer keys, and replays receive-queue
-walks with a batched lock-step Fenwick sweep.  This module provides the
-device implementations of all three:
+walks with a batched lock-step Fenwick sweep.  This module provides all
+three on three backends (:data:`BACKENDS`):
 
+``backend='numpy'`` (also what ``backend=None`` means)
+    ``np.bincount`` / ``np.maximum.at`` and the host Fenwick walk of
+    :func:`repro.comm.primitives.batched_queue_traversal_steps`: the
+    bit-identity reference, and the fallback of both device backends.
 ``backend='jax'``
     ``jax.ops.segment_sum`` / ``segment_max`` under ``jax.jit`` and a jitted
     ``lax.fori_loop`` Fenwick walk (:func:`queue_walk`) — the scalable
@@ -20,12 +24,9 @@ device implementations of all three:
     per-lane Fenwick walk needs dynamic gathers and scatters that Mosaic
     cannot lower either.  On hosts without a TPU the kernel runs in
     interpret mode (parity, not speed).
-``backend='auto'`` (the resolved form of ``backend=None``)
-    The autotuned default: picks numpy below the measured numpy/jax
-    crossover size and jax at/above it (:func:`autotune_crossover`).
 
-numpy is the bit-identity reference and the silent fallback when jax is
-absent (:func:`resolve_backend` warns once for explicit device requests).
+:func:`resolve_backend` validates a name and falls back to numpy, with a
+warning once, when jax is absent or the device backend is quarantined.
 Backend parity for the float reductions is *allclose*, not bit-equal (the
 device paths run float32); the queue walk is integer work and bit-equal on
 every backend.
@@ -37,10 +38,7 @@ backend failure falls back to the numpy reference, warns once, and is
 recorded in :class:`repro.comm.health.BackendHealth`, which quarantines a
 backend after repeated consecutive failures.  The optional
 ``REPRO_STACK_VERIFY`` post-kernel check (``finite`` | ``parity``) detects
-silent NaN/mismatch in device outputs and triggers the same fallback.  The
-autotune probe is bounded by a cooperative timeout with
-retry-and-backoff, and its disk cache tolerates corruption and read-only
-directories.
+silent NaN/mismatch in device outputs and triggers the same fallback.
 
 This module imports jax lazily so that importing it — and everything in
 :mod:`repro.comm` — stays numpy-only.
@@ -49,20 +47,17 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import json
 import os
-import time
 
 import numpy as np
 
 from repro.comm import faults, obs
 from repro.comm.health import get_health
 
-BACKENDS = ("numpy", "jax", "pallas", "auto")
+BACKENDS = ("numpy", "jax", "pallas")
 
 _CHUNK = 512        # messages per fused-kernel grid step
 _LANE = 128         # segments per fused-kernel output tile
-_SEG_BLOCK = _LANE  # historical alias (the retired one-hot kernel's block)
 
 
 def have_jax() -> bool:
@@ -73,36 +68,29 @@ def have_jax() -> bool:
         return False
 
 
-def resolve_backend(backend: str | None = None,
-                    n_values: int | None = None) -> str:
+def resolve_backend(backend: str | None = None) -> str:
     """Resolve a backend request to a concrete backend name.
 
-    ``None`` means the *autotuned default* (``'auto'``).  ``'auto'`` picks
-    numpy below the measured numpy/jax crossover size and jax at/above it;
-    pass ``n_values`` (the reduction's input length) to collapse it to a
-    concrete choice here — without ``n_values`` the string ``'auto'`` is
-    returned for the caller to resolve per call.  Explicit ``'jax'`` /
-    ``'pallas'`` requests fall back to numpy with a warning (once per
-    process, via the resettable :class:`repro.comm.health.BackendHealth`
-    registry) when jax is not importable or the backend is quarantined
-    after repeated failures; ``'auto'`` falls back silently (it is a
-    default, not a request).
+    ``None`` means numpy.  An unknown name raises ``ValueError`` naming the
+    allowed values (:data:`BACKENDS`).  ``'jax'`` / ``'pallas'`` fall back
+    to numpy with a warning (once per process, via the resettable
+    :class:`repro.comm.health.BackendHealth` registry) when jax is not
+    importable or the backend is quarantined after repeated failures.
     """
     if backend is None:
-        backend = "auto"
+        return "numpy"
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown stack backend {backend!r}; expected one of {BACKENDS}")
-    if backend != "numpy" and not have_jax():
-        if backend != "auto":
-            get_health().warn_once(
-                f"nojax:{backend}",
-                f"stack backend {backend!r} requested but jax is not "
-                "importable; falling back to numpy")
+    if backend == "numpy":
+        return backend
+    if not have_jax():
+        get_health().warn_once(
+            f"nojax:{backend}",
+            f"stack backend {backend!r} requested but jax is not "
+            "importable; falling back to numpy")
         return "numpy"
-    if backend == "auto" and n_values is not None:
-        backend = "numpy" if n_values < autotune_crossover() else "jax"
-    if backend in ("jax", "pallas") and get_health().is_quarantined(backend):
+    if get_health().is_quarantined(backend):
         get_health().warn_once(
             f"resolve-quarantined:{backend}",
             f"stack backend {backend!r} is quarantined after repeated "
@@ -225,170 +213,6 @@ def _device_call(site: str):
     return obs.span("repro.device." + site)
 
 
-# -- autotuned numpy/jax crossover -------------------------------------------
-
-#: probe sizes for the crossover search (geometric, covers the realistic
-#: arena range on both CPU-only and accelerator hosts)
-_PROBE_SIZES = (1 << 13, 1 << 15, 1 << 17, 1 << 19)
-_PROBE_SEGMENTS = 256
-
-_crossover: float | None = None
-
-
-def _probe_tag() -> str:
-    """Cache key tying a persisted probe to the software/device stack."""
-    parts = [np.__version__]
-    try:
-        import jax
-        parts += [jax.__version__, jax.default_backend()]
-    except Exception:  # pragma: no cover - environment-dependent
-        parts.append("nojax")
-    return "/".join(parts)
-
-
-def _best_time(fn, reps: int = 3) -> float:
-    fn()                                              # warm (jit, caches)
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fn()
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def _probe_pair(n: int) -> tuple[float, float]:
-    """(numpy, jax) best-of times for one packed-key segment sum of ``n``."""
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(0)
-    ids = rng.integers(0, _PROBE_SEGMENTS, size=n)
-    vals = rng.random(n)
-    t_np = _best_time(
-        lambda: np.bincount(ids, weights=vals, minlength=_PROBE_SEGMENTS))
-    seg_sum, _ = _jax_segment_ops()
-    d_vals = jax.device_put(jnp.asarray(vals, jnp.float32))
-    d_ids = jax.device_put(jnp.asarray(ids, jnp.int32))
-    t_jax = _best_time(
-        lambda: seg_sum(d_vals, d_ids, _PROBE_SEGMENTS).block_until_ready())
-    return t_np, t_jax
-
-
-#: Live-probe hardening: per-size retry attempts, base backoff seconds
-#: (doubling per retry), and the cooperative probe deadline (seconds,
-#: override with ``REPRO_STACK_PROBE_TIMEOUT``).
-_PROBE_RETRIES = 3
-_PROBE_BACKOFF = 0.05
-_PROBE_TIMEOUT = 60.0
-
-
-def _read_probe_cache(path: str, tag: str) -> float | None:
-    """The cached crossover at ``path``, or None when the cache is absent,
-    unreadable, corrupt, or tagged for a different software stack (a
-    corrupt cache is recorded as a health event and reprobed, never
-    trusted and never fatal)."""
-    if not os.path.exists(path):
-        return None
-    try:
-        faults.fail_point("autotune.cache_read")
-        with open(path) as fh:
-            raw = faults.poison("autotune.cache_read", fh.read())
-        rec = json.loads(raw)
-        if rec.get("tag") == tag:
-            return float(rec["crossover"])
-    except (OSError, ValueError, KeyError, TypeError) as e:
-        get_health().record_failure("disk-cache", "autotune.cache_read", e)
-    return None
-
-
-def _write_probe_cache(path: str, tag: str, cross: float) -> None:
-    """Persist a probe result; a read-only/failing cache directory is a
-    recorded health event, not an error (the probe result still serves the
-    process from the in-memory memo)."""
-    try:
-        faults.fail_point("autotune.cache_write")
-        with open(path, "w") as fh:
-            json.dump({"tag": tag, "crossover": cross,
-                       "sizes": list(_PROBE_SIZES)}, fh)
-    except OSError as e:
-        get_health().record_failure("disk-cache", "autotune.cache_write", e)
-
-
-def _probe_crossover() -> float:
-    """Run the live probe under a cooperative deadline with per-size
-    retry-and-backoff; degrades to ``inf`` (numpy always) when the probe
-    keeps failing or the deadline passes — a strategy-service query must
-    never hang or crash on a misbehaving probe."""
-    deadline = time.monotonic() + float(
-        os.environ.get("REPRO_STACK_PROBE_TIMEOUT", _PROBE_TIMEOUT))
-    for n in _PROBE_SIZES:
-        for attempt in range(_PROBE_RETRIES):
-            try:
-                if time.monotonic() > deadline:
-                    raise TimeoutError(
-                        f"autotune probe deadline exceeded before size {n}")
-                faults.fail_point("autotune.probe")
-                t_np, t_jax = _probe_pair(n)
-            except TimeoutError as e:
-                # the deadline is global: no point retrying or probing on
-                get_health().record_failure("autotune", "autotune.probe", e)
-                return float("inf")
-            except Exception as e:  # noqa: BLE001 - degradation
-                get_health().record_failure("autotune", "autotune.probe", e)
-                if attempt + 1 == _PROBE_RETRIES:
-                    return float("inf")
-                time.sleep(_PROBE_BACKOFF * 2 ** attempt)
-            else:
-                if t_jax < t_np:
-                    return float(n)
-                break                      # this size settled: next size
-    return float("inf")
-
-
-def autotune_crossover(refresh: bool = False) -> float:
-    """The measured input size where the jitted jax segment reduction starts
-    beating numpy's ``bincount`` (``float('inf')`` when it never does — e.g.
-    CPU-only jax, or jax absent).
-
-    Resolution order: in-process memo -> ``REPRO_STACK_AUTOTUNE`` env
-    override (a number, ``inf`` allowed) -> on-disk probe cache (the path in
-    ``REPRO_STACK_AUTOTUNE_CACHE``, ignored — with a recorded health event —
-    when corrupt or when its software tag no longer matches) -> a live probe
-    over ``_PROBE_SIZES`` with device-resident inputs (first size where jax
-    wins).  ``refresh=True`` forces a new probe and rewrites the disk cache.
-    The probe costs a few jit compiles once per process; pin the env var to
-    skip it entirely.
-
-    Hardened for service use: the probe runs under a cooperative deadline
-    (``REPRO_STACK_PROBE_TIMEOUT`` seconds) with retry-and-backoff per
-    size, and every failure path — probe timeout, corrupt cache, read-only
-    cache directory — degrades to a usable crossover (``inf`` = numpy)
-    instead of raising.
-    """
-    global _crossover
-    if _crossover is not None and not refresh:
-        return _crossover
-    env = os.environ.get("REPRO_STACK_AUTOTUNE")
-    if env is not None and not refresh:
-        _crossover = float(env)
-        return _crossover
-    path = os.environ.get("REPRO_STACK_AUTOTUNE_CACHE")
-    tag = _probe_tag()
-    if path and not refresh:
-        cached = _read_probe_cache(path, tag)
-        if cached is not None:
-            _crossover = cached
-            return _crossover
-    if not have_jax():
-        _crossover = float("inf")
-        return _crossover
-    cross = _probe_crossover()
-    _crossover = cross
-    if path:
-        _write_probe_cache(path, tag, cross)
-    return cross
-
-
 # -- jitted segment reductions ----------------------------------------------
 
 @functools.cache
@@ -443,10 +267,6 @@ def to_host(a, dtype=None) -> np.ndarray:
     obs.count("device.syncs")
     obs.count("device.d2h_bytes", a.nbytes)
     return out
-
-
-def _size_of(a) -> int:
-    return int(a.size) if hasattr(a, "size") else len(a)
 
 
 # -- fused Pallas segment reduce ---------------------------------------------
@@ -636,12 +456,11 @@ def _segment_max_numpy(values, seg_ids, n_seg: int) -> np.ndarray:
 def segment_sum(values, seg_ids, n_seg: int,
                 backend: str | None = None) -> np.ndarray:
     """Sum ``values`` into ``n_seg`` bins by ``seg_ids`` on the chosen
-    backend (``None``/``'auto'`` = the autotuned default).  Device inputs
-    (jax arrays) stay resident on the jax path; the reduced dense result is
-    returned on the host.  Device-backend failures degrade to the numpy
-    reference via :func:`device_guard` (site ``kernel.segment_reduce``)."""
-    if backend in (None, "auto"):
-        backend = resolve_backend("auto", n_values=_size_of(seg_ids))
+    backend (``None`` = numpy).  Device inputs (jax arrays) stay resident
+    on the jax path; the reduced dense result is returned on the host.
+    Device-backend failures degrade to the numpy reference via
+    :func:`device_guard` (site ``kernel.segment_reduce``)."""
+    backend = resolve_backend(backend)
     if backend == "numpy":
         return _segment_sum_numpy(values, seg_ids, n_seg)
     if backend == "pallas":
@@ -661,11 +480,11 @@ def segment_sum(values, seg_ids, n_seg: int,
 def segment_max(values, seg_ids, n_seg: int,
                 backend: str | None = None) -> np.ndarray:
     """Per-segment maximum (0.0 for empty segments, matching the stacked
-    contention reduction where all inputs are non-negative byte counts).
-    Device-backend failures degrade to the numpy reference via
-    :func:`device_guard` (site ``kernel.segment_reduce``)."""
-    if backend in (None, "auto"):
-        backend = resolve_backend("auto", n_values=_size_of(seg_ids))
+    contention reduction where all inputs are non-negative byte counts)
+    on the chosen backend (``None`` = numpy).  Device-backend failures
+    degrade to the numpy reference via :func:`device_guard` (site
+    ``kernel.segment_reduce``)."""
+    backend = resolve_backend(backend)
     if backend == "numpy":
         return _segment_max_numpy(values, seg_ids, n_seg)
     if backend == "pallas":
@@ -764,7 +583,8 @@ def _jax_queue_walk(depth: int):
 
 
 def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarray:
-    """Batched receive-queue walk lengths on the chosen backend.
+    """Batched receive-queue walk lengths on the chosen backend (``None`` =
+    numpy).
 
     Same contract as
     :func:`repro.comm.primitives.batched_queue_traversal_steps` (region
@@ -779,10 +599,7 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
     (site ``kernel.queue_walk``) — bit-identically, since the walk is
     integer work.
     """
-    if backend in (None, "auto"):
-        backend = resolve_backend("auto", n_values=_size_of(posted))
-    else:
-        backend = resolve_backend(backend)
+    backend = resolve_backend(backend)
 
     def numpy_fn():
         from repro.comm.primitives import batched_queue_traversal_steps
@@ -809,32 +626,3 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
         return to_host(steps, np.int64)
 
     return device_guard("kernel.queue_walk", backend, device_fn, numpy_fn)
-
-
-# -- deprecated one-hot era shims --------------------------------------------
-
-#: Deprecated: the retired one-hot kernel's work ceiling.  The fused tiled
-#: kernel works per (tile, chunk) pair, so no limit applies; the
-#: constant is kept (with :func:`pallas_within_limit`) so external callers
-#: written against the old reroute logic keep working.
-PALLAS_ONE_HOT_LIMIT = 1 << 24
-
-
-def pallas_within_limit(n_values: int, n_seg: int) -> bool:
-    """Deprecated: always True.
-
-    The one-hot Pallas kernel this guarded was replaced by the fused tiled
-    kernel (:func:`fused_segment_reduce`), which reduces only the (segment
-    tile x message chunk) pairs that overlap — there is no work ceiling and
-    no jax reroute.  Warns once
-    per process (via the resettable
-    :class:`repro.comm.health.BackendHealth` registry), then delegates to
-    the new behaviour (every size is within limit).
-    """
-    get_health().warn_once(
-        "kernels.one_hot_deprecated",
-        "pallas_within_limit/PALLAS_ONE_HOT_LIMIT are deprecated: the "
-        "one-hot kernel was replaced by a fused tiled kernel with no "
-        "size limit; the pallas backend now handles "
-        "every request directly", category=DeprecationWarning, stacklevel=3)
-    return True

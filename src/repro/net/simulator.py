@@ -206,9 +206,7 @@ def simulate_many(phases,
     :func:`simulate`.  A ``DeltaStack`` serves transport and contention from
     its incrementally-maintained caches.  ``backend`` selects the arena's
     reduction backend (as in :meth:`repro.comm.PhaseStack.sim_arrays`;
-    ``None`` defaults to ``REPRO_STACK_BACKEND`` or numpy, ``'auto'`` is
-    the autotuned per-call choice) and is ignored on the per-phase
-    fallback path.
+    ``None`` is numpy) and is ignored on the per-phase fallback path.
     """
     if noise > 0.0 and rng is None:
         rng = np.random.default_rng(0)

@@ -12,9 +12,7 @@ Three pieces, all numpy-free and jax-free, shared by
   oversized request degrades to serial admission instead of wedging forever.
 
 * :class:`Deadline` — a cooperative per-request deadline over a monotonic
-  clock, the same pattern the autotune probe uses
-  (:mod:`repro.kernels.comm_stack`): construct once, call :meth:`check` at
-  loop points.  Armed deadlines pass through the ``serve.deadline`` fault
+  clock: construct once, call :meth:`check` at loop points.  Armed deadlines pass through the ``serve.deadline`` fault
   site, so a chaos run can expire any request deterministically.
 
 * :class:`RetryPolicy` — deterministic jittered exponential backoff for the

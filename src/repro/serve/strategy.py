@@ -202,7 +202,7 @@ class StrategyService:
         strat = ",".join(strategies) if strategies else "auto"
         self._config_token = (f"{mname}|{getattr(machine, 'n_procs', '?')}|"
                               f"{level}|{arrival}|{seed}|{strat}|"
-                              f"{backend or 'auto'}")
+                              f"{backend or 'numpy'}")
 
     # -- introspection --------------------------------------------------------
     def health(self):
@@ -314,7 +314,7 @@ class StrategyService:
         from repro.comm.health import get_health
 
         health = get_health()
-        backend_label = str(self.backend or "auto")
+        backend_label = str(self.backend or "numpy")
 
         def sweep(idx, strats, backend):
             return _strategies.best_strategy_many(

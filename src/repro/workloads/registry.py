@@ -168,7 +168,7 @@ def sweep(scenarios=DEFAULT_SCENARIOS, machines=None,
     mixed-machine candidate set stacks per machine group inside — at model
     ladder ``level`` with one arrival ``seed`` on the stacked-pass
     ``backend`` (as in :func:`~repro.comm.strategies.best_strategy_many`;
-    None is the session default).  Returns one
+    None is numpy).  Returns one
     :class:`SweepRow` per (machine, scenario, phase), machines in dict
     order, scenarios in input order; rows priced under a backend fallback
     carry ``degraded=True``.

@@ -4,12 +4,10 @@ Covers the DESIGN.md §12 contract end to end: the deterministic
 fault-injection framework (:mod:`repro.comm.faults`), the per-site
 degradation wrappers in :mod:`repro.kernels.comm_stack` and
 :mod:`repro.comm.stack`, the :class:`repro.comm.health.BackendHealth`
-quarantine ledger, the hardened autotune cache/probe, and — the acceptance
+quarantine ledger, and — the acceptance
 criterion — the PR-7 scenario-registry sweep under every fault mode,
 bit-identical to a clean numpy run with ``degraded=True`` on every row.
 """
-import json
-
 import numpy as np
 import pytest
 
@@ -27,7 +25,6 @@ requires_jax = pytest.mark.skipif(not cs.have_jax(), reason="needs jax")
 def test_site_and_mode_registries():
     assert set(faults.SITES) == {
         "kernel.segment_reduce", "kernel.queue_walk", "stack.device_store",
-        "autotune.probe", "autotune.cache_read", "autotune.cache_write",
         "serve.cache_read", "serve.cache_write", "serve.deadline"}
     assert set(faults.MODES) == {"raise", "timeout", "nan", "corrupt"}
 
@@ -44,9 +41,9 @@ def test_spec_glob_matching():
     assert spec.matches("kernel.segment_reduce")
     assert spec.matches("kernel.queue_walk")
     assert not spec.matches("stack.device_store")
-    exact = FaultSpec(site="autotune.probe", mode="timeout")
-    assert exact.matches("autotune.probe")
-    assert not exact.matches("autotune.cache_read")
+    exact = FaultSpec(site="serve.deadline", mode="timeout")
+    assert exact.matches("serve.deadline")
+    assert not exact.matches("serve.cache_read")
 
 
 def test_fail_point_fires_and_counts():
@@ -69,10 +66,10 @@ def test_times_caps_firing():
 
 
 def test_timeout_mode_is_a_timeout_error():
-    with inject("autotune.cache_read", "timeout"):
+    with inject("serve.cache_read", "timeout"):
         with pytest.raises(TimeoutError):
-            faults.fail_point("autotune.cache_read")
-        with inject("autotune.cache_read", "timeout"):
+            faults.fail_point("serve.cache_read")
+        with inject("serve.cache_read", "timeout"):
             pass
     # InjectedTimeout is also an OSError, so disk-cache handlers catch it
     assert issubclass(InjectedTimeout, OSError)
@@ -108,12 +105,12 @@ def test_innermost_context_fires_first():
 
 def test_env_plan_parses_globs_and_times(monkeypatch):
     monkeypatch.setenv(faults.ENV_VAR,
-                       "kernel.*:raise, autotune.probe:timeout:1")
+                       "kernel.*:raise, serve.deadline:timeout:1")
     with pytest.raises(InjectedFault):
         faults.fail_point("kernel.segment_reduce")
     with pytest.raises(InjectedTimeout):
-        faults.fail_point("autotune.probe")
-    faults.fail_point("autotune.probe")             # times=1 exhausted
+        faults.fail_point("serve.deadline")
+    faults.fail_point("serve.deadline")             # times=1 exhausted
     with pytest.raises(InjectedFault):
         faults.fail_point("kernel.queue_walk")      # unbounded glob spec
 
@@ -245,111 +242,6 @@ def test_poison_without_verify_passes_through(monkeypatch):
     assert get_health().n_events == 0
 
 
-# -- autotune hardening (disk cache + probe) ----------------------------------
-
-@pytest.fixture
-def autotune_env(monkeypatch, tmp_path):
-    """Fresh autotune state: no env override, no memo, a tmp cache path."""
-    monkeypatch.delenv("REPRO_STACK_AUTOTUNE", raising=False)
-    path = tmp_path / "autotune.json"
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE_CACHE", str(path))
-    old = cs._crossover
-    cs._crossover = None
-    yield path
-    cs._crossover = old
-
-
-def test_cache_read_corrupt_file_degrades(autotune_env):
-    autotune_env.write_text("{not json!")
-    assert cs._read_probe_cache(str(autotune_env), cs._probe_tag()) is None
-    events = get_health().events_for("disk-cache", "autotune.cache_read")
-    assert len(events) == 1
-
-
-def test_cache_read_wrong_schema_degrades(autotune_env):
-    autotune_env.write_text(json.dumps({"tag": cs._probe_tag(),
-                                        "crossover": None}))
-    assert cs._read_probe_cache(str(autotune_env), cs._probe_tag()) is None
-    assert get_health().events_for("disk-cache", "autotune.cache_read")
-
-
-def test_cache_read_stale_tag_is_not_an_event(autotune_env):
-    autotune_env.write_text(json.dumps({"tag": "other-stack",
-                                        "crossover": 4096.0}))
-    assert cs._read_probe_cache(str(autotune_env), cs._probe_tag()) is None
-    assert get_health().n_events == 0     # a stale tag is normal, not a fault
-
-
-def test_cache_read_fault_injected(autotune_env):
-    autotune_env.write_text(json.dumps({"tag": cs._probe_tag(),
-                                        "crossover": 4096.0}))
-    tag = cs._probe_tag()
-    assert cs._read_probe_cache(str(autotune_env), tag) == 4096.0
-    reset_health()
-    with inject("autotune.cache_read", "timeout"):
-        assert cs._read_probe_cache(str(autotune_env), tag) is None
-    assert get_health().events_for("disk-cache", "autotune.cache_read")
-    with inject("autotune.cache_read", "corrupt"):    # garbled file text
-        assert cs._read_probe_cache(str(autotune_env), tag) is None
-
-
-def test_cache_write_unwritable_path_degrades(tmp_path):
-    blocker = tmp_path / "not_a_dir"
-    blocker.write_text("")
-    path = blocker / "cache.json"                 # NotADirectoryError
-    cs._write_probe_cache(str(path), cs._probe_tag(), 4096.0)
-    events = get_health().events_for("disk-cache", "autotune.cache_write")
-    assert len(events) == 1
-
-
-def test_cache_write_fault_injected(autotune_env):
-    with inject("autotune.cache_write", "timeout"):
-        cs._write_probe_cache(str(autotune_env), cs._probe_tag(), 4096.0)
-    assert not autotune_env.exists()
-    assert get_health().events_for("disk-cache", "autotune.cache_write")
-
-
-@requires_jax
-def test_probe_timeout_degrades_to_numpy_always(autotune_env):
-    with inject("autotune.probe", "timeout") as spec:
-        assert cs._probe_crossover() == float("inf")
-    assert spec.fired == 1                        # a timeout ends the probe
-    assert get_health().events_for("autotune", "autotune.probe")
-
-
-@requires_jax
-def test_probe_retries_then_degrades(autotune_env):
-    with inject("autotune.probe", "raise") as spec:
-        assert cs._probe_crossover() == float("inf")
-    # non-timeout failures retry with backoff before giving up
-    assert spec.fired == cs._PROBE_RETRIES
-    assert len(get_health().events_for("autotune",
-                                       "autotune.probe")) == cs._PROBE_RETRIES
-
-
-@requires_jax
-def test_autotune_end_to_end_corrupt_cache_then_probe_fault(autotune_env):
-    autotune_env.write_text("junk{{{")
-    with inject("autotune.probe", "timeout"):
-        assert cs.autotune_crossover(refresh=False) == float("inf")
-    sites = {e.site for e in get_health().events}
-    assert sites == {"autotune.cache_read", "autotune.probe"}
-    # the degraded probe result was still persisted for the next process
-    assert json.loads(autotune_env.read_text())["crossover"] == float("inf")
-
-
-def test_autotune_env_override_skips_probe(monkeypatch):
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE", "4096")
-    old = cs._crossover
-    cs._crossover = None
-    try:
-        with inject("autotune.*", "raise"):
-            assert cs.autotune_crossover() == 4096.0
-    finally:
-        cs._crossover = old
-    assert get_health().n_events == 0
-
-
 # -- stack + sweep degradation ------------------------------------------------
 
 def _small_pattern():
@@ -416,15 +308,13 @@ def test_chaos_registry_sweep_bit_identical_to_clean_numpy(mode, verify,
     numpy run, and marks every row degraded with events in the ledger."""
     from repro.workloads.registry import default_machines, sweep
 
-    monkeypatch.setenv("REPRO_STACK_BACKEND", "numpy")
-    clean = sweep(machines=default_machines())
+    clean = sweep(machines=default_machines(), backend="numpy")
     assert clean and not any(r.degraded for r in clean)
 
     reset_health()
-    monkeypatch.setenv("REPRO_STACK_BACKEND", "jax")
     monkeypatch.setenv("REPRO_STACK_VERIFY", verify)
     monkeypatch.setenv(faults.ENV_VAR, f"*:{mode}")
-    chaos = sweep(machines=default_machines())
+    chaos = sweep(machines=default_machines(), backend="jax")
 
     assert get_health().n_events > 0
     assert all(r.degraded for r in chaos)

@@ -8,13 +8,13 @@ Three surfaces:
 * ``queue_walk`` — the device-resident Fenwick queue walk, bit-equal to
   :func:`repro.comm.primitives.batched_queue_traversal_steps` (the walk is
   integer-exact, so every backend must agree exactly).
-* ``resolve_backend`` / ``autotune_crossover`` — the 'auto' policy: env
-  override, disk cache round-trip, and the numpy-below / jax-above split.
+* ``backend=None`` — numpy at every entry point that takes a backend,
+  and ``'auto'`` rejected at each of them.
 
 Property tests ride the optional-hypothesis shim and skip cleanly when
 hypothesis is absent; the deterministic parity tests always run.
 """
-import json
+import dataclasses
 
 import numpy as np
 import pytest
@@ -193,67 +193,7 @@ def test_grouped_queue_steps_backend_parity(backend):
     np.testing.assert_array_equal(got, want)
 
 
-# ------------------------------------------------ auto policy ---------------
-@pytest.fixture
-def fresh_autotune(monkeypatch):
-    """Reset the crossover memo and isolate env overrides per test."""
-    monkeypatch.setattr(cs, "_crossover", None)
-    monkeypatch.delenv("REPRO_STACK_AUTOTUNE", raising=False)
-    monkeypatch.delenv("REPRO_STACK_AUTOTUNE_CACHE", raising=False)
-    yield
-    cs._crossover = None
-
-
-def test_resolve_backend_auto_env_override(fresh_autotune, monkeypatch):
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE", "1000")
-    assert cs.resolve_backend("auto", n_values=999) == "numpy"
-    if cs.have_jax():
-        assert cs.resolve_backend("auto", n_values=1000) == "jax"
-        assert cs.resolve_backend(None, n_values=10 ** 9) == "jax"
-    assert cs.resolve_backend(None) == "auto"        # no size: defer
-
-
-def test_resolve_backend_auto_inf_always_numpy(fresh_autotune, monkeypatch):
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE", "inf")
-    assert cs.resolve_backend("auto", n_values=1 << 40) == "numpy"
-
-
-def test_autotune_disk_cache_round_trip(fresh_autotune, monkeypatch, tmp_path):
-    if not cs.have_jax():
-        pytest.skip("autotune probe needs jax")
-    cache = tmp_path / "autotune.json"
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE_CACHE", str(cache))
-    first = cs.autotune_crossover(refresh=True)
-    assert cache.exists()
-    payload = json.loads(cache.read_text())
-    assert payload["tag"] == cs._probe_tag()
-    # a fresh memo must come from the cache file, not a re-probe: poison the
-    # stored value and check it is believed verbatim
-    payload["crossover"] = 12345.0
-    cache.write_text(json.dumps(payload))
-    cs._crossover = None
-    assert cs.autotune_crossover() == 12345.0
-    assert first == first                      # probe result itself was finite-or-inf
-
-
-def test_autotune_cache_ignored_on_tag_mismatch(fresh_autotune, monkeypatch,
-                                                tmp_path):
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE", "2048")   # pin: no live probe
-    cache = tmp_path / "autotune.json"
-    cache.write_text(json.dumps({"tag": "someone-elses-machine",
-                                 "crossover": 7.0}))
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE_CACHE", str(cache))
-    assert cs.autotune_crossover() == 2048.0   # env wins over a stale cache
-
-
-def test_backends_tuple_includes_auto():
-    assert "auto" in cs.BACKENDS
-    assert "auto" in PhaseStack.__init__.__module__ or True  # sanity import
-    from repro.comm.stack import STACK_BACKENDS
-    assert STACK_BACKENDS == cs.BACKENDS
-
-
-# ------------------------------------------------ stack-level auto ----------
+# ------------------------------------------------ one default: numpy -------
 BW = blue_waters_machine((2, 2, 2))
 
 
@@ -269,26 +209,134 @@ def _bw_phases(n_phases=3, n=150, seed=0):
     return out
 
 
-def test_stack_auto_high_crossover_is_bit_identical_to_numpy(
-        fresh_autotune, monkeypatch):
-    """auto -> numpy below the crossover: byte-for-byte the numpy path."""
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE", "inf")
-    stack = PhaseStack.build(_bw_phases())
-    a = stack.cost_arrays(backend="auto")
-    b = stack.cost_arrays(backend="numpy")
+def test_stack_auto_high_crossover_is_bit_identical_to_numpy():
+    """``backend=None`` is numpy: byte-for-byte the numpy path, on fresh
+    arenas (no cache shared between the two calls)."""
+    a = PhaseStack.build(_bw_phases()).cost_arrays(backend=None)
+    b = PhaseStack.build(_bw_phases()).cost_arrays(backend="numpy")
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
 
 
+def _segments():
+    return _random_segments(np.random.default_rng(3), 4000, 700) + (700,)
+
+
+def _arrivals(phases):
+    rng = np.random.default_rng(9)
+    return [ph.random_arrival_flat(rng) for ph in phases]
+
+
+def _delta():
+    from repro.comm import DeltaStack
+    return DeltaStack.from_phases(_bw_phases())
+
+
+def _digest(**kw):
+    from repro.exec import build_schedule, delivered_digest, run_reference
+    phase = _bw_phases(n_phases=1, n=60, seed=4)[0]
+    sched = build_schedule(phase, "standard")
+    return delivered_digest(run_reference(sched), sched, **kw)
+
+
+def _best_many(**kw):
+    from repro.comm.strategies import best_strategy_many
+    return [(v.model, v.sim, v.model_winner, v.sim_winner, v.degraded)
+            for v in best_strategy_many(_bw_phases(n_phases=2, n=80),
+                                        **kw)]
+
+
+def _phase_cost_many(**kw):
+    from repro.core.models import phase_cost_many
+    return phase_cost_many(_bw_phases(), **kw)
+
+
+def _simulate_many(**kw):
+    from repro.net.simulator import simulate_many
+    phases = _bw_phases()
+    return simulate_many(phases, arrival_orders=_arrivals(phases), **kw)
+
+
+def _sim_arrays(stack_of, **kw):
+    phases = _bw_phases()
+    out = stack_of(phases).sim_arrays(arrival_orders=_arrivals(phases),
+                                      **kw)
+    return (out.transport, out.per_proc, out.qsteps, out.max_link,
+            out.net_bytes)
+
+
+def _queue_steps_many(**kw):
+    phases = _bw_phases()
+    return PhaseStack.build(phases).queue_steps_many(
+        arrival_orders=_arrivals(phases), **kw)
+
+
+#: every pricing entry point that takes ``backend``, called on fresh inputs
+ENTRY_POINTS = {
+    "kernels.segment_sum": lambda **kw: cs.segment_sum(*_segments(), **kw),
+    "kernels.segment_max": lambda **kw: cs.segment_max(*_segments(), **kw),
+    "kernels.queue_walk": lambda **kw: cs.queue_walk(
+        *_random_regions(np.random.default_rng(2), 30, 20), **kw),
+    "kernels.resolve_backend": lambda **kw: cs.resolve_backend(
+        kw.get("backend")),
+    "PhaseStack.cost_arrays": lambda **kw: PhaseStack.build(
+        _bw_phases()).cost_arrays(node_aware=False, **kw),
+    "PhaseStack.sim_arrays": lambda **kw: _sim_arrays(PhaseStack.build,
+                                                      **kw),
+    "PhaseStack.queue_steps_many": _queue_steps_many,
+    "PhaseStack.link_contention_many": lambda **kw: PhaseStack.build(
+        _bw_phases()).link_contention_many(**kw),
+    "DeltaStack.cost_arrays": lambda **kw: _delta().cost_arrays(**kw),
+    "DeltaStack.sim_arrays": lambda **kw: _sim_arrays(
+        lambda phases: _delta(), **kw),
+    "phase_cost_many": _phase_cost_many,
+    "simulate_many": _simulate_many,
+    "best_strategy_many": _best_many,
+    "exec.delivered_digest": _digest,
+}
+
+
+def _assert_bit_equal(got, want):
+    if isinstance(got, (tuple, list)):
+        assert type(got) is type(want) and len(got) == len(want)
+        for g, w in zip(got, want):
+            _assert_bit_equal(g, w)
+    elif isinstance(got, np.ndarray):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+    elif dataclasses.is_dataclass(got):
+        assert type(got) is type(want)
+        for f in dataclasses.fields(got):
+            _assert_bit_equal(getattr(got, f.name), getattr(want, f.name))
+    else:
+        assert got == want
+
+
 @needs_jax
-def test_stack_auto_low_crossover_takes_device_path(fresh_autotune,
-                                                    monkeypatch):
-    monkeypatch.setenv("REPRO_STACK_AUTOTUNE", "1")
-    stack = PhaseStack.build(_bw_phases())
-    a = stack.cost_arrays(backend="auto")
-    b = stack.cost_arrays(backend="numpy")
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(x, y, rtol=2e-4, atol=1e-12)
+@pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
+def test_default_backend_is_numpy(entry):
+    """Omitting ``backend`` prices exactly as ``backend='numpy'`` and never
+    reaches a device call site."""
+    from repro.comm import obs
+    call = ENTRY_POINTS[entry]
+    obs.reset()
+    obs.enable()
+    try:
+        got = call()
+        device_calls = {k: v for k, v in obs.counters().items()
+                        if k.startswith("device.calls.")}
+    finally:
+        obs.disable()
+        obs.reset()
+    assert device_calls == {}
+    _assert_bit_equal(got, call(backend="numpy"))
+
+
+@pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
+def test_auto_backend_rejected(entry):
+    """``'auto'`` is no backend: every entry point names the three."""
+    with pytest.raises(ValueError, match=r"numpy.*jax.*pallas"):
+        ENTRY_POINTS[entry](backend="auto")
 
 
 # ------------------------------------------------ streaming build -----------
@@ -325,11 +373,3 @@ def test_build_streaming_rejects_bad_chunk_and_empty_ok():
     # an empty iterable mirrors build([]): a valid zero-message stack
     empty = PhaseStack.build_streaming([])
     assert empty.total_msgs == 0
-
-
-def test_deprecated_one_hot_shim_still_importable():
-    from repro.comm.health import reset_health
-    assert cs.PALLAS_ONE_HOT_LIMIT == 1 << 24
-    reset_health()                       # clear the warn-once registry
-    with pytest.warns(DeprecationWarning, match="fused tiled kernel"):
-        assert cs.pallas_within_limit(1 << 30, 1 << 20) is True

@@ -7,7 +7,6 @@ backend), including empty phases, single-message phases and custom receive
 orders.  The optional JAX/Pallas backends are held to allclose parity
 (they run float32).
 """
-import warnings
 
 import numpy as np
 import pytest
@@ -324,7 +323,7 @@ def test_unknown_backend_raises():
 
 def test_backend_error_is_eager_and_lists_allowed_values():
     """Validation happens before any reduction and names every legal value
-    plus where the bad name came from (kwarg vs env var)."""
+    plus where the bad name came from (the backend argument)."""
     stack = PhaseStack.build(_sweep(BW, seed=33))
     with pytest.raises(ValueError, match=r"numpy.*jax.*pallas"):
         stack.cost_arrays(backend="rocm")
@@ -336,39 +335,27 @@ def test_backend_error_is_eager_and_lists_allowed_values():
         stack.link_contention_many(backend="rocm")
 
 
-def test_env_backend_validated_eagerly(monkeypatch):
-    monkeypatch.setenv("REPRO_STACK_BACKEND", "cuda")
+def test_env_backend_validated_eagerly():
+    """The retired ``'auto'`` is rejected before any pass runs, and the
+    error names the three backends."""
     stack = PhaseStack.build(_sweep(BW, seed=35))
-    with pytest.raises(ValueError, match="REPRO_STACK_BACKEND"):
-        stack.cost_arrays()
-    with pytest.raises(ValueError, match="REPRO_STACK_BACKEND"):
-        simulate_many(stack)
+    allowed = r"\('numpy', 'jax', 'pallas'\)"
+    with pytest.raises(ValueError, match=allowed):
+        stack.cost_arrays(backend="auto")
+    with pytest.raises(ValueError, match=allowed):
+        simulate_many(stack, backend="auto")
 
 
-# ------------------------------------------------- pallas size guard --------
+# ------------------------------------------------- kernels backend list ---
 from repro.kernels import comm_stack as _cs  # numpy-safe import
 
 
 def test_stack_backends_mirror_kernels():
     """The eagerly-validated tuple (kept kernels-import-free in stack.py)
-    must never drift from the kernels module's own backend list."""
+    must never drift from the kernels module's own backend list, and both
+    are exactly the three backends."""
     from repro.comm import STACK_BACKENDS
-    assert STACK_BACKENDS == _cs.BACKENDS
-
-
-def test_pallas_one_hot_shim_warns_once_and_allows_everything():
-    """The one-hot work ceiling is retired: the deprecation shim warns once
-    per process (via the resettable health registry), then reports every
-    size as within limit (the fused scatter-accumulate kernel is
-    O(messages), no reroute exists)."""
-    from repro.comm.health import reset_health
-    reset_health()                       # clear the warn-once registry
-    with pytest.warns(DeprecationWarning, match="fused tiled kernel"):
-        assert _cs.pallas_within_limit(1, 1)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")          # a second warning would raise
-        assert _cs.pallas_within_limit(
-            _cs.PALLAS_ONE_HOT_LIMIT, _cs.PALLAS_ONE_HOT_LIMIT)
+    assert STACK_BACKENDS == _cs.BACKENDS == ("numpy", "jax", "pallas")
 
 
 @needs_jax
@@ -378,7 +365,7 @@ def test_pallas_handles_sizes_beyond_retired_one_hot_limit(op):
     directly on the fused pallas kernel and match numpy."""
     fn = _cs.segment_sum if op == "sum" else _cs.segment_max
     rng = np.random.default_rng(0)
-    n_seg = _cs.PALLAS_ONE_HOT_LIMIT // _cs._CHUNK + 1   # over the old limit
+    n_seg = (1 << 24) // _cs._CHUNK + 1   # over the one-hot kernel's limit
     vals = rng.random(4 * _cs._CHUNK)
     ids = rng.integers(0, n_seg, vals.size)
     want = fn(vals, ids, n_seg, backend="numpy")
@@ -387,16 +374,15 @@ def test_pallas_handles_sizes_beyond_retired_one_hot_limit(op):
 
 
 @needs_jax
-def test_env_backend_cannot_poison_numpy_caches(monkeypatch):
-    """REPRO_STACK_BACKEND must not leak float32 accelerator results into
-    the bit-exact numpy arena caches (they pin backend='numpy' internally)."""
+def test_env_backend_cannot_poison_numpy_caches():
+    """Pricing an arena on a device backend first must not leak float32
+    results into its bit-exact numpy caches (they pin backend='numpy')."""
     phases = _sweep(BW, seed=37)
     want = phase_cost_many(PhaseStack.build(phases))      # clean numpy run
-    monkeypatch.setenv("REPRO_STACK_BACKEND", "jax")
     stack = PhaseStack.build(phases)
-    got = phase_cost_many(stack, backend="numpy")
-    assert got == want
-    monkeypatch.delenv("REPRO_STACK_BACKEND")
+    phase_cost_many(stack, backend="jax")
+    simulate_many(stack, backend="jax")
+    assert phase_cost_many(stack, backend="numpy") == want
     assert phase_cost_many(stack) == want                 # cache stayed clean
     _assert_results_equal(simulate_many(stack),
                           [simulate(ph) for ph in phases])
