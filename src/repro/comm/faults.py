@@ -13,7 +13,7 @@ Sites (:data:`SITES`):
 
 ==========================  =================================================
 ``kernel.segment_reduce``   jitted/Pallas segment sum/max reductions
-``kernel.queue_walk``       the device Fenwick queue sweep
+``kernel.queue_walk``       the device queue walk (dominance tiles)
 ``stack.device_store``      arena column shipping to the device
 ``serve.cache_read``        strategy-service arena-cache read
 ``serve.cache_write``       strategy-service arena-cache write

@@ -240,10 +240,10 @@ def grouped_queue_steps(group, n_slots, recv_post_order=None,
     ``groups`` optionally supplies a precomputed ``(order, bounds)`` stable
     grouping (e.g. :meth:`repro.comm.CommPhase.receiver_groups`); ``describe``
     renders a slot id in error messages.  ``backend`` selects where the
-    Fenwick sweep itself runs (``None``/``'numpy'`` = the in-process numpy
-    rounds; ``'jax'``/``'pallas'`` = the fused device walk in
-    :func:`repro.kernels.comm_stack.queue_walk` — bit-equal, it is integer
-    work).
+    walk itself runs (``None``/``'numpy'`` = the in-process numpy Fenwick
+    rounds; ``'jax'``/``'pallas'`` = the device walk in
+    :func:`repro.kernels.comm_stack.queue_walk`, dominance counts in
+    dense tiles — bit-equal, it is integer work).
     """
     group = np.asarray(group, dtype=np.int64)
     if describe is None:

@@ -29,7 +29,7 @@ O(n_phases) trivial slice-sums; all per-message work stays in the single
 pass).
 
 Device backends (``backend='jax' | 'pallas'``) route the packed-key
-transport/contention reductions and the Fenwick queue sweep through
+transport/contention reductions and the queue walk through
 :mod:`repro.kernels.comm_stack`, with the hot per-message columns cached
 device-resident on first use (one transfer per arena, not per call) and the
 message pricing itself run under the backend's array namespace
@@ -678,11 +678,12 @@ class PhaseStack:
         ``recv_post_orders[i]`` / ``arrival_orders[i]`` are phase ``i``'s
         per-receiver order dicts (phase-local message indices, exactly what
         :meth:`CommPhase.queue_steps` takes).  All phases' custom receivers
-        run in ONE lock-step Fenwick sweep: the rounds needed are the *max*
-        messages-per-receiver over the whole stack, not the per-phase sum.
-        ``backend`` selects where the sweep runs — the device walk
-        (:func:`repro.kernels.comm_stack.queue_walk`) executes all rounds in
-        one fused program and, being integer work, is *bit-equal* to numpy.
+        run in ONE batched walk: on numpy a lock-step Fenwick sweep whose
+        rounds are the *max* messages-per-receiver over the whole stack,
+        not the per-phase sum.  ``backend`` selects where the walk runs —
+        the device walk (:func:`repro.kernels.comm_stack.queue_walk`)
+        counts every receiver's steps as dominance counts in one program
+        and, being integer work, is *bit-equal* to numpy.
         """
         P = self.proc_span
         backend_name, _ = self._backend(backend)

@@ -3,27 +3,29 @@
 The stacked sweep engine (:mod:`repro.comm.stack`) reduces per-message
 quantities to per-(phase, process) / per-(phase, link) aggregates with
 segmented sums/maxima over packed integer keys, and replays receive-queue
-walks with a batched lock-step Fenwick sweep.  This module provides all
-three on three backends (:data:`BACKENDS`):
+walks.  This module provides all three on three backends
+(:data:`BACKENDS`):
 
 ``backend='numpy'`` (also what ``backend=None`` means)
     ``np.bincount`` / ``np.maximum.at`` and the host Fenwick walk of
     :func:`repro.comm.primitives.batched_queue_traversal_steps`: the
     bit-identity reference, and the fallback of both device backends.
 ``backend='jax'``
-    ``jax.ops.segment_sum`` / ``segment_max`` under ``jax.jit`` and a jitted
-    ``lax.fori_loop`` Fenwick walk (:func:`queue_walk`) — the scalable
-    path: O(total messages) scatter work, the whole queue sweep one device
-    program with no host round-trip between rounds.
+    ``jax.ops.segment_sum`` / ``segment_max`` under ``jax.jit`` and the
+    jitted queue walk (:func:`queue_walk`): a walk length is a dominance
+    count, ``1 + #{k > j : b_k < b_j}`` over the later arrivals of a
+    receive queue (``b`` the posted slot of each arrival), so the queues
+    are counted in dense compare-and-sum tiles, one per power-of-two
+    length class, with no gather or scatter.  A call with a queue of more
+    than :data:`TILE_MAX` arrivals runs the numpy walk instead.
 ``backend='pallas'``
     A fused Pallas segment reduce.  :func:`fused_segment_reduce` sorts the
     keys on the host and reduces one-hot membership tiles of (segment tile
     x message chunk) pairs — sums and maxima in one launch, with no vector
     scatter (Mosaic has no lowering for one) and a bounded VMEM tile at any
-    arena size.  The queue walk is the jitted XLA walk of ``'jax'``: a
-    per-lane Fenwick walk needs dynamic gathers and scatters that Mosaic
-    cannot lower either.  On hosts without a TPU the kernel runs in
-    interpret mode (parity, not speed).
+    arena size.  The queue walk is the jitted XLA walk of ``'jax'``.  On
+    hosts without a TPU the kernel runs in interpret mode (parity, not
+    speed).
 
 :func:`resolve_backend` validates a name and falls back to numpy, with a
 warning once, when jax is absent or the device backend is quarantined.
@@ -503,83 +505,132 @@ def segment_max(values, seg_ids, n_seg: int,
                         lambda: _segment_max_numpy(values, seg_ids, n_seg))
 
 
-# -- device Fenwick queue walk -----------------------------------------------
+# -- device queue walk: dominance tiles --------------------------------------
+#
+# A walk length is a dominance count.  Let ``b_j`` be the posted slot of a
+# region's j-th arrival.  The slots below ``b_j`` still posted when it
+# arrives are exactly those matched by later arrivals, so it matches at
+#
+#     steps_j = 1 + #{k > j : b_k < b_j}
+#
+# in the unmatched queue.  Each region is padded to its class ``C`` (the
+# next power of two, at least ``_TILE_MIN``) and laid out with the class's
+# other regions as one dense int32 tile ``[C, R]``: row ``j`` holds the j-th
+# arrival of each of the ``R`` regions, so the regions sit on the lanes, and
+# pads hold ``_PAD``, which is never below a posted slot.  Each tile is one
+# fused compare-and-sum (a long class's in blocks of rows), with no gather
+# or scatter.  Tile work grows as ``C^2`` a region, so a call with a region
+# longer than ``TILE_MAX`` takes the host Fenwick walk instead.
+
+#: Longest region (arrivals) the dominance tiles take; a call with a longer
+#: region runs the host Fenwick walk of
+#: :func:`repro.comm.primitives.batched_queue_traversal_steps`.  Its
+#: derivation is in DESIGN.md, "The queue walk on device".
+TILE_MAX = 1 << 14
+_TILE_MIN = 8                       # smallest class: a sublane tile's rows
+_BLOCK = 1 << 24                    # compares of one tile block
+_PAD = np.iinfo(np.int32).max       # tile cells past a region's arrivals
+
+
+def _pow2_ceil(n) -> np.ndarray:
+    """The least power of two at or above each of ``n`` (1 where n <= 1)."""
+    n = np.asarray(n, dtype=np.int64)
+    return np.left_shift(1, np.frexp(np.maximum(n - 1, 0))[1]).astype(
+        np.int64)
+
 
 def _queue_layout(posted, arrival, bounds):
-    """Host-side layout for the lock-step Fenwick sweep (mirrors the numpy
-    reference in :func:`repro.comm.primitives.batched_queue_traversal_steps`
-    exactly: same private-tree packing, same initial tree contents)."""
-    from repro.comm.primitives import segmented_arange
+    """Host layout of :func:`queue_walk`: ``(cells, dest, classes)``.
 
+    ``cells`` (int32) holds the class tiles one after another, each
+    ``[C, R]`` flattened; ``dest`` is each arrival's index in ``cells``, in
+    the layout of ``posted``; ``classes`` is ``((C, R), ...)`` by ascending
+    ``C``.  Every index depends on ``bounds`` alone; only the slots come
+    from the orders.
+    """
     posted = np.asarray(posted, dtype=np.int64)
     arrival = np.asarray(arrival, dtype=np.int64)
     bounds = np.asarray(bounds, dtype=np.int64)
     N = int(posted.size)
-    starts = bounds[:-1]
     counts = np.diff(bounds)
     region_of = np.repeat(np.arange(counts.size), counts)
-    start_of = starts[region_of]
+    start_of = bounds[:-1][region_of]
+    local = np.arange(N) - start_of
     pos = np.empty(N, dtype=np.int64)
-    pos[start_of + posted] = np.arange(N) - start_of
+    pos[start_of + posted] = local
     b = pos[start_of + arrival]                       # slot of j-th arrival
-    span = np.ones(counts.size, dtype=np.int64)
-    while (span < counts).any():
-        span = np.where(span < counts, span * 2, span)
-    blk = span + 1
-    toff = np.concatenate([[0], np.cumsum(blk)])
-    tree = np.zeros(toff[-1] + 1, dtype=np.int64)     # +1: shared sink
-    li = segmented_arange(blk)
-    c_rep = np.repeat(counts, blk)
-    lo = li - (li & -li)
-    tree[:-1] = np.minimum(li, c_rep) - np.minimum(lo, c_rep)
-    depth = int(span.max(initial=1)).bit_length()
-    rounds = int(counts.max(initial=0))
-    return tree, b, starts, counts, toff[:-1], span, depth, rounds
+    # non-empty regions, class by class, each a column of its class's tile
+    tiled = np.flatnonzero(counts)
+    width = np.maximum(_pow2_ceil(counts[tiled]), _TILE_MIN)
+    by_class = np.argsort(width, kind="stable")
+    tiled, width = tiled[by_class], width[by_class]
+    cw, first, n_per = np.unique(width, return_index=True,
+                                 return_counts=True)
+    off = np.concatenate([[0], np.cumsum(cw * n_per)])
+    cls = np.repeat(np.arange(cw.size), n_per)
+    base = np.zeros(counts.size, dtype=np.int64)
+    stride = np.ones(counts.size, dtype=np.int64)
+    base[tiled] = off[cls] + np.arange(tiled.size) - first[cls]
+    stride[tiled] = n_per[cls]
+    dest = base[region_of] + local * stride[region_of]
+    cells = np.full(int(off[-1]), _PAD, dtype=np.int32)
+    cells[dest] = b
+    return cells, dest, tuple(zip(cw.tolist(), n_per.tolist()))
 
 
-@functools.cache
-def _jax_queue_walk(depth: int):
-    """Jitted lock-step Fenwick sweep: all rounds in one ``fori_loop``, no
-    host round-trip between rounds.  ``depth`` (the per-round chain length)
-    is static and unrolled; shapes retrace per arena layout.  This is the
-    queue walk of both device backends: XLA lowers its per-lane gathers and
-    scatters on every device, Mosaic does not."""
+def _tile_steps(x):
+    """``1 + #{k > j : x[k, r] < x[j, r]}`` for every cell of a class tile
+    ``x`` ``[C, R]`` (traced).  Where too few regions share a long class
+    to fill the lanes, the tile is turned so that ``k`` takes them.  A
+    tile of more than ``_BLOCK`` compares is taken in blocks of rows
+    ``j``, each against every row ``k``."""
     import jax
     import jax.numpy as jnp
 
-    def walk(tree, b, starts, counts, toff, span, rounds):
-        sink = tree.shape[0] - 1
-        steps0 = jnp.zeros(b.shape, dtype=tree.dtype)
+    C, R = x.shape
+    turn = R < _LANE <= C
+    y, ax = (x.T, 1) if turn else (x, 0)
+    rows = min(C, 1 << (max(1, _BLOCK // (C * R)).bit_length() - 1))
+    k = jnp.arange(C, dtype=jnp.int32)
 
-        def round_body(j, state):
-            tree, steps = state
-            mask = counts > j
-            s = jnp.where(mask, starts + j, 0)
-            p = jnp.where(mask, b[s] + 1, 0)
-            # prefix: maskless gathers (a chain that reaches 0 keeps
-            # reading its region's always-zero root)
-            i = p
-            acc = jnp.zeros_like(p)
-            for _ in range(depth):
-                acc = acc + tree[toff + i]
-                i = i - (i & -i)
-            steps = steps.at[s].add(jnp.where(mask, acc, 0))
-            # removal: chains past the region span (and inactive regions)
-            # park at the shared sink slot, which is never read
-            i = p
-            bound = jnp.where(mask, span, -1)
-            idx = jnp.where(mask, toff + i, sink)
-            delta = jnp.where(mask, -1, 0).astype(tree.dtype)
-            for _ in range(depth):
-                tree = tree.at[idx].add(delta)
-                i = i + (i & -i)
-                idx = jnp.where(i > bound, sink, toff + i)
-            return tree, steps
+    def block(j0):
+        yj = jax.lax.dynamic_slice_in_dim(y, j0, rows, axis=ax)
+        j = j0 + jnp.arange(rows, dtype=jnp.int32)
+        if turn:                                      # [R, j, k]
+            hit = (y[:, None, :] < yj[:, :, None]) & (k > j[:, None])
+            return 1 + jnp.sum(hit, axis=2, dtype=jnp.int32)
+        hit = (y[None] < yj[:, None]) & (k[:, None] > j[:, None, None])
+        return 1 + jnp.sum(hit, axis=1, dtype=jnp.int32)  # [j, k, R]
 
-        _, steps = jax.lax.fori_loop(0, rounds, round_body, (tree, steps0))
-        return steps
+    if rows == C:
+        out = block(0)
+    else:
+        out = jax.lax.fori_loop(
+            0, C // rows,
+            lambda i, out: jax.lax.dynamic_update_slice_in_dim(
+                out, block(i * rows), i * rows, ax),
+            jnp.zeros_like(y))
+    return out.T if turn else out
 
-    return jax.jit(walk)
+
+@functools.cache
+def _jax_queue_walk():
+    """The jitted queue walk of both device backends, ``walk(cells,
+    classes=...)``: every class tile of ``cells`` as dominance counts, in
+    one program that returns the steps in the layout of ``cells``.  Shapes
+    retrace per arena layout."""
+    import jax
+    import jax.numpy as jnp
+
+    def walk(cells, classes):
+        outs, o = [], 0
+        for c, r in classes:
+            outs.append(_tile_steps(cells[o:o + c * r].reshape(c, r))
+                        .reshape(-1))
+            o += c * r
+        return jnp.concatenate(outs)
+
+    return jax.jit(walk, static_argnames="classes")
 
 
 def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarray:
@@ -589,12 +640,18 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
     Same contract as
     :func:`repro.comm.primitives.batched_queue_traversal_steps` (region
     ``r`` owns slots ``bounds[r]:bounds[r+1]`` of ``posted``/``arrival``;
-    returns per-arrival steps in the same layout).  The walk is integer
-    work, so every backend is bit-equal to the numpy reference — the device
-    path runs all rounds in one program instead of one host-synced array
-    pass per round.  ``'jax'`` and ``'pallas'`` share that program
-    (:func:`_jax_queue_walk`).  Index arithmetic runs in int32 on device
-    (arenas beyond 2^31 - 1 queue slots must use numpy).  Device-backend
+    returns per-arrival steps in the same layout).  On ``'jax'`` and
+    ``'pallas'`` alike, one jitted program (:func:`_jax_queue_walk`)
+    counts, for each arrival, the later arrivals of its region posted
+    before it, as dense dominance tiles, one per power-of-two length
+    class.  The host lays the tiles out and takes the steps back out of
+    them.  A call with a region of more than :data:`TILE_MAX` arrivals, or
+    with 2^31 - 1 tile cells or more, runs the numpy reference instead.
+    The walk is integer work, so every backend is bit-equal to the numpy
+    reference.  With :mod:`repro.comm.obs` on, each call counts
+    ``walk.tile_regions``, ``walk.fenwick_regions`` (non-empty regions
+    counted in the tiles and by the host Fenwick walk) and
+    ``walk.tile_cells`` (tile cells, pads included).  Device-backend
     failures degrade to the numpy reference via :func:`device_guard`
     (site ``kernel.queue_walk``) — bit-identically, since the walk is
     integer work.
@@ -608,21 +665,21 @@ def queue_walk(posted, arrival, bounds, backend: str | None = None) -> np.ndarra
     if backend == "numpy":
         return numpy_fn()
 
-    with obs.span("repro.kernel.layout"):
-        tree, b, starts, counts, toff, span, depth, rounds = _queue_layout(
-            posted, arrival, bounds)
-    N = int(b.size)
-    if N == 0 or rounds == 0:
-        return np.zeros(N, dtype=np.int64)
-    if tree.size - 1 >= np.iinfo(np.int32).max:       # pragma: no cover
+    counts = np.diff(np.asarray(bounds, dtype=np.int64))
+    if not counts.any():
+        return np.zeros(int(np.asarray(posted).size), dtype=np.int64)
+    if counts.max() > TILE_MAX:
+        obs.count("walk.fenwick_regions", int(np.count_nonzero(counts)))
         return numpy_fn()
+    with obs.span("repro.kernel.layout"):
+        cells, dest, classes = _queue_layout(posted, arrival, bounds)
+    if cells.size >= _PAD:                            # pragma: no cover
+        return numpy_fn()
+    obs.count("walk.tile_regions", sum(r for _, r in classes))
+    obs.count("walk.tile_cells", int(cells.size))
 
     def device_fn():
-        import jax.numpy as jnp
-        walk = _jax_queue_walk(depth)
-        steps = walk(*(to_device(a, jnp.int32)
-                       for a in (tree, b, starts, counts, toff, span)),
-                     rounds)
-        return to_host(steps, np.int64)
+        steps = _jax_queue_walk()(to_device(cells), classes=classes)
+        return to_host(steps)[dest].astype(np.int64)
 
     return device_guard("kernel.queue_walk", backend, device_fn, numpy_fn)

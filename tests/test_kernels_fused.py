@@ -5,7 +5,8 @@ Three surfaces:
 * ``fused_segment_reduce`` / ``segment_sum`` / ``segment_max`` — the tiled
   scatter-accumulate bincount kernel that replaced the one-hot matmul, on
   ragged / empty / single-message inputs, against the numpy reference.
-* ``queue_walk`` — the device-resident Fenwick queue walk, bit-equal to
+* ``queue_walk`` — the device queue walk (dominance tiles; the numpy
+  walk past ``TILE_MAX``), bit-equal to
   :func:`repro.comm.primitives.batched_queue_traversal_steps` (the walk is
   integer-exact, so every backend must agree exactly).
 * ``backend=None`` — numpy at every entry point that takes a backend,
@@ -20,7 +21,8 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
-from repro.comm import CommPhase, PhaseStack
+from repro.comm import CommPhase, PhaseStack, obs
+from repro.comm.health import get_health
 from repro.comm.primitives import (batched_queue_traversal_steps,
                                    grouped_queue_steps)
 from repro.kernels import comm_stack as cs
@@ -134,7 +136,11 @@ def test_property_fused_reduce_parity(n, n_seg, seed):
 
 # ------------------------------------------------ device queue walk ---------
 def _random_regions(rng, n_regions, max_count):
-    counts = rng.integers(0, max_count + 1, n_regions)
+    return _regions(rng, rng.integers(0, max_count + 1, n_regions))
+
+
+def _regions(rng, counts):
+    """Random posted and arrival orders for regions of ``counts`` messages."""
     bounds = np.concatenate([[0], np.cumsum(counts)])
     posted, arrival = [], []
     for c in counts:
@@ -145,16 +151,59 @@ def _random_regions(rng, n_regions, max_count):
     return cat(posted), cat(arrival), bounds.astype(np.int64)
 
 
+#: Walk arenas: ``(n_regions, max_count)`` for random region lengths, or
+#: a list of region lengths.  Lengths on every tile class boundary; a
+#: class of 256 with more than 256 regions, taken in blocks of rows; one
+#: region of 5000 beside short ones, its class turned and taken in blocks;
+#: and one region just past ``TILE_MAX``, which sends the call to the
+#: numpy walk.
+_WALK_ARENAS = (
+    [pytest.param((n, m), id=f"{n}-{m}")
+     for n, m in [(1, 1), (3, 0), (5, 9), (40, 25), (2, 200)]]
+    + [pytest.param([L] * 3 + [0, 5], id=f"class-edge-{L}")
+       for L in (1, 7, 8, 9, 63, 64, 65, 255, 256, 257)]
+    + [pytest.param([200] * 300, id="blocked-rows"),
+       pytest.param([3, 5000, 0, 40, 9], id="turned-blocked"),
+       pytest.param([3, cs.TILE_MAX + 1, 0, 40, 9], id="past-tile-max")])
+
+
 @needs_jax
 @pytest.mark.parametrize("backend", DEVICE_BACKENDS)
-@pytest.mark.parametrize("n_regions,max_count", [(1, 1), (3, 0), (5, 9),
-                                                 (40, 25), (2, 200)])
-def test_queue_walk_bit_equal_to_numpy(backend, n_regions, max_count):
-    rng = np.random.default_rng(n_regions * 1000 + max_count)
-    posted, arrival, bounds = _random_regions(rng, n_regions, max_count)
+@pytest.mark.parametrize("arena", _WALK_ARENAS)
+def test_queue_walk_bit_equal_to_numpy(backend, arena):
+    if isinstance(arena, tuple):
+        n_regions, max_count = arena
+        rng = np.random.default_rng(n_regions * 1000 + max_count)
+        posted, arrival, bounds = _random_regions(rng, n_regions, max_count)
+    else:
+        posted, arrival, bounds = _regions(np.random.default_rng(len(arena)),
+                                           arena)
     want = batched_queue_traversal_steps(posted, arrival, bounds)
     got = cs.queue_walk(posted, arrival, bounds, backend=backend)
+    assert not get_health().events_for(backend, "kernel.queue_walk")
     np.testing.assert_array_equal(got, want)   # integer walk: exact
+
+
+@needs_jax
+@pytest.mark.parametrize("counts,fenwick", [
+    ([4, 0, 8, 9, 200, 1], 0),
+    ([cs.TILE_MAX, 2, 0], 0),
+    ([cs.TILE_MAX + 1, 2, cs.TILE_MAX, 0], 3)])
+def test_queue_walk_counts_regions_per_path(counts, fenwick):
+    posted, arrival, bounds = _regions(np.random.default_rng(5), counts)
+    obs.reset()
+    obs.enable()
+    try:
+        cs.queue_walk(posted, arrival, bounds, backend="jax")
+    finally:
+        obs.disable()
+    c = obs.counters()
+    obs.reset()
+    nonempty = sum(n > 0 for n in counts)
+    tiles = c.get("walk.tile_regions", 0)
+    assert tiles + c.get("walk.fenwick_regions", 0) == nonempty
+    assert c.get("walk.fenwick_regions", 0) == fenwick
+    assert c.get("walk.tile_cells", 0) >= (sum(counts) if tiles else 0)
 
 
 @needs_jax

@@ -2,9 +2,10 @@
 
 The device programs of the main path are compiled at their real sizes for
 a described ``v5e:2x2`` topology: the Pallas segment reduce (interpret
-mode on a CPU host hides what Mosaic refuses), the jitted queue walk, and
-the lowered 4-rank exchange.  Nothing runs, so these say nothing about
-results or times; ``chip_smoke.py`` runs the same programs on a chip.
+mode on a CPU host hides what Mosaic refuses), the jitted queue walk (its
+dominance tiles), and the lowered 4-rank exchange.  Nothing runs, so
+these say nothing about results or times; ``chip_smoke.py`` runs the same
+programs on a chip.
 
 The topology is described inside a fixture, never at import: only the
 worker that runs this file loads the TPU compiler.
@@ -79,16 +80,33 @@ def test_segment_reduce_compiles_for_v5e(one_chip, no_persistent_cache):
 
 
 def test_jax_queue_walk_compiles_for_v5e(one_chip, no_persistent_cache):
-    # one receive queue per (phase, rank) slot of the AMG arena, 2^20
-    # arrivals, Fenwick trees of span 32 (depth 6)
-    n_regions, depth = N_SEG, 6
-    tree = n_regions * 33 + 1
-    compiled = cs._jax_queue_walk(depth).lower(
-        _shape((tree,), jnp.int32, one_chip),
-        _shape((N_MSGS,), jnp.int32, one_chip),
-        *[_shape((n_regions,), jnp.int32, one_chip)] * 4,
-        _shape((), jnp.int32, one_chip)).compile()
-    assert "while" in compiled.as_text()
+    # the longest class the tiles take: TILE_MAX arrivals in one and in 16
+    # receive queues, each turned and taken in blocks of rows (a loop)
+    classes = ((cs.TILE_MAX, 1), (cs.TILE_MAX, 16))
+    compiled = cs._jax_queue_walk().lower(
+        _shape((sum(c * r for c, r in classes),), jnp.int32, one_chip),
+        classes=classes).compile()
+    text = compiled.as_text()
+    assert "while" in text
+    assert "gather" not in text and "scatter" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+#: Tile classes ``(C, R)`` of amg-sweep's queue walk: 604,398 arrivals in
+#: 38,289 receive queues, the longest 174
+AMG_WALK_CLASSES = ((8, 16512), (16, 15770), (32, 2642), (64, 1472),
+                    (128, 1552), (256, 341))
+
+
+def test_queue_walk_tiles_compile_for_v5e(one_chip, no_persistent_cache):
+    n_cells = sum(c * r for c, r in AMG_WALK_CLASSES)
+    compiled = cs._jax_queue_walk().lower(
+        _shape((n_cells,), jnp.int32, one_chip),
+        classes=AMG_WALK_CLASSES).compile()
+    text = compiled.as_text()
+    assert "gather" not in text and "scatter" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20
 
 
 def _four_rank_phase():
